@@ -7,9 +7,12 @@ Table-7/8 gradient sizes).  Every feasible in-SPMD schedule is timed per
 layout (one row per strategy) against the host-staged mpr baseline, with
 the Table-2 model's predicted speedup alongside.
 
-Runs in a subprocess with 8 host devices so the main process keeps one.
-Under ``benchmarks/run.py --quick`` these rows land in BENCH_lgr.json and
-sit behind the standard >2x regression gate.
+Runs in a subprocess on the CPU backend with 8 host devices
+(``JAX_PLATFORMS=cpu`` set explicitly), so the rows time EMULATED
+collectives, never a chip, and the child never competes with the parent
+for an accelerator.  A failed child raises.  Under
+``benchmarks/run.py --quick`` these rows land in BENCH_lgr.json and sit
+behind the standard >2x regression gate.
 """
 from __future__ import annotations
 
@@ -76,13 +79,14 @@ def run():
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                           capture_output=True, text=True, timeout=900,
                           cwd=os.path.dirname(os.path.dirname(
                               os.path.abspath(__file__))))
     if proc.returncode != 0:
-        emit("lgr_table7", 0.0, f"FAILED:{proc.stderr[-200:]}")
-        return
+        raise RuntimeError(f"bench_lgr child exited {proc.returncode}:\n"
+                           f"{proc.stderr[-2000:]}")
     data = json.loads(proc.stdout.strip().splitlines()[-1])
     sizes = {"AT": 110_000, "HM": 290_000, "SH": 1_500_000}
     cm = ReduceCostModel()

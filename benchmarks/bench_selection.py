@@ -9,7 +9,7 @@ from repro.core.selection import explore, make_ppo_profiler
 
 
 def run(bench: str = "Ant"):
-    profile = make_ppo_profiler(iters=1)
+    profile = make_ppo_profiler(iters=1, mem_budget_bytes=32e9)
     t0 = time.perf_counter()
     trace = explore(profile, bench, num_gpu=4,
                     gmi_per_gpu_range=(4, 2, 1),

@@ -156,6 +156,8 @@ def main() -> None:
         # vs without chunked prefill (asserted smaller)
         bench_serving.run_paged()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     suites = [
         ("serving", serving_suite),

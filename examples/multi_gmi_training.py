@@ -29,7 +29,7 @@ def main():
 
     # 1) Algorithm 2: profile-driven configuration search (reduced sweep)
     print("== Algorithm 2: workload-aware GMI selection ==")
-    profile = make_ppo_profiler(iters=1)
+    profile = make_ppo_profiler(iters=1, mem_budget_bytes=32e9)
     trace = explore(profile, args.env, num_gpu=args.num_gpus,
                     gmi_per_gpu_range=(2, 1), num_env_sweep=(128, 256, 512))
     num_env, gmi_per_gpu = trace.best_config

@@ -41,7 +41,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 STRATEGIES = ("mpr", "mrr", "har", "har3")
@@ -58,12 +57,10 @@ def _finalize_average(tree, count: int, average: bool):
 
 
 def _axis_count(axis_names) -> int:
-    """Static participant count inside an SPMD body: psum of a Python
-    literal folds to the axis size on every jax version this repo
-    supports — the one call path that never probes a live buffer."""
+    """Static participant count over ``axis_names`` inside an SPMD body."""
     n = 1
     for a in axis_names:
-        n *= jax.lax.psum(1, a)
+        n *= jax.lax.axis_size(a)
     return n
 
 
@@ -190,7 +187,7 @@ def lgr_allreduce(grads, mesh: Mesh, strategy: str, *,
     spec = P(*axes)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: spec, grads),),
         out_specs=jax.tree.map(lambda _: spec, grads))
     def run(gs):

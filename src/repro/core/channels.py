@@ -142,7 +142,8 @@ class ChannelRing:
         assert slots >= 1
         self.slots = int(slots)
         self.double_buffered = bool(double_buffered)
-        self.use_pallas = (jax.default_backend() == "tpu") \
+        # on TPU the compiled pack kernel; on the CPU backend the XLA twin
+        self.use_pallas = (not ops._interpret_default()) \
             if use_pallas is None else use_pallas
         self.interpret = interpret
         self.bufs: Optional[Dict[str, jax.Array]] = None

@@ -114,13 +114,33 @@ def is_resource_exhausted(err: BaseException) -> bool:
     return any(m in str(err) for m in _RESOURCE_MARKERS)
 
 
-def make_ppo_profiler(iters: int = 3, mem_budget_bytes: float = 32e9):
+def device_memory_budget(device=None) -> float:
+    """Bytes the backend lets one device allocate
+    (``memory_stats()["bytes_limit"]``); raises where the backend reports
+    no limit (the CPU backend), so callers there must pass a budget."""
+    import jax
+    dev = device if device is not None else jax.devices()[0]
+    stats = dev.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        raise ValueError(
+            f"{dev.platform} device reports no bytes_limit; pass "
+            "mem_budget_bytes explicitly")
+    return float(stats["bytes_limit"])
+
+
+def make_ppo_profiler(iters: int = 3,
+                      mem_budget_bytes: Optional[float] = None):
     """Times actual PPO iterations on this host.  GMIperGPU scales the
     simulated per-instance resource slice by shrinking num_env headroom
-    (1/GMIperGPU of the device), mirroring MPS percentage caps."""
+    (1/GMIperGPU of the device), mirroring MPS percentage caps.
+
+    ``mem_budget_bytes`` defaults to the device's reported allocation
+    limit (:func:`device_memory_budget`)."""
     import jax
     from repro.envs import make_env
     from repro.rl import ppo
+    if mem_budget_bytes is None:
+        mem_budget_bytes = device_memory_budget()
 
     def profile(bench: str, gmi_per_gpu: int, num_env: int) -> ProfilePoint:
         env = make_env(bench)

@@ -32,10 +32,7 @@ _EMBED_KEYS = ("embed", "unembed", "table", "head")
 
 
 def _axis_sizes(mesh) -> Dict[str, int]:
-    try:
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    except AttributeError:                      # concrete Mesh without
-        return dict(mesh.shape)                 # .axis_sizes (older jax)
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def _path_names(path) -> Tuple[str, ...]:

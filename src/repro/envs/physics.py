@@ -83,10 +83,14 @@ def counter_normal(seed, counter, idx):
     base = hash_u32(s ^ (c * jnp.uint32(0x9E3779B9)))
     h1 = hash_u32(base + i * jnp.uint32(2) + jnp.uint32(1))
     h2 = hash_u32(base + i * jnp.uint32(2) + jnp.uint32(2))
-    # 24-bit mantissa uniforms; u1 offset into (0, 1] so log never sees 0
-    u1 = (h1 >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / 16777216.0) \
-        + (0.5 / 16777216.0)
-    u2 = (h2 >> jnp.uint32(8)).astype(jnp.float32) * (1.0 / 16777216.0)
+    # 24-bit mantissa uniforms; u1 offset into (0, 1] so log never sees 0.
+    # The 24-bit value goes to float through int32 (exact, and the cast
+    # Mosaic lowers; it has no uint32 -> float32 conversion)
+    def unit(h):
+        return (h >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32) \
+            * (1.0 / 16777216.0)
+    u1 = unit(h1) + (0.5 / 16777216.0)
+    u2 = unit(h2)
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     return r * jnp.cos((2.0 * jnp.pi) * u2)
 

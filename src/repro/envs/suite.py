@@ -14,6 +14,8 @@ raw physical features to exactly the published observation dimension.
 """
 from __future__ import annotations
 
+import zlib
+
 import numpy as np
 
 import jax
@@ -43,9 +45,18 @@ _TASK = {
 }
 
 
+def sensor_seed(name: str) -> int:
+    """Process-independent seed of an env's sensor matrix."""
+    return zlib.crc32(name.encode()) % (2 ** 31)
+
+
 def _sensor_matrix(name: str, raw_dim: int, obs_dim: int) -> jnp.ndarray:
-    """Fixed orthonormal-ish projection raw -> obs (deterministic per env)."""
-    seed = abs(hash(name)) % (2 ** 31)
+    """Fixed orthonormal-ish projection raw -> obs (deterministic per env).
+
+    Seeded from a CRC-32 of the name, not ``hash``: Python salts string
+    hashes per process, which made the env (and the compiled vmap path,
+    where this matrix is a constant) differ from run to run."""
+    seed = sensor_seed(name)
     rng = np.random.RandomState(seed)
     m = rng.randn(raw_dim, obs_dim).astype(np.float32)
     # orthonormalize columns where possible for a well-conditioned sensor map

@@ -38,10 +38,11 @@ rejected on the Table-8 workload:
 The staged-generation pack has no donation anywhere, so serving runs
 ahead of the trainer's consumption limited only by ring capacity.
 
-All six channels are packed by ONE ``pallas_call`` (grid (1,)): the slot
-index rides in SMEM and every ring buffer is aliased input->output, so the
-kernel performs six in-place dynamic stores and never touches the
-untouched slots.  On CPU/GPU backends the identical program is lowered
+The four (T, N)-shaped channels are packed by ONE ``pallas_call`` tiled
+over env blocks: the slot index rides the scalar prefetch into the
+output index maps and every ring buffer is aliased input->output, so
+the kernel writes only the slot's column block and never touches the
+untouched slots.  On the CPU backend the identical program is lowered
 through XLA ``dynamic_update_slice`` (``pack_channels_xla``) — donated and
 jitted, so it is also an in-place pointer-bump where the runtime allows.
 
@@ -86,18 +87,25 @@ def _as_payloads(payloads: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
 
 
 # ----------------------------------------------------------------- pallas --
-def _kernel(slot_ref, obs_p, act_p, rew_p, done_p, boot_p, ver_p,
-            obs_i, act_i, rew_i, done_i, boot_i, ver_i,
-            obs_o, act_o, rew_o, done_o, boot_o, ver_o, *, n_env):
-    del obs_i, act_i, rew_i, done_i, boot_i, ver_i  # aliased to outputs
-    s = slot_ref[0, 0]
-    col = s * n_env
-    obs_o[:, pl.ds(col, n_env), :] = obs_p[...]
-    act_o[:, pl.ds(col, n_env), :] = act_p[...]
-    rew_o[:, pl.ds(col, n_env)] = rew_p[...]
-    done_o[:, pl.ds(col, n_env)] = done_p[...]
-    boot_o[pl.ds(s, 1), :] = boot_p[...]
-    ver_o[pl.ds(s, 1), :] = ver_p[...]
+def _env_block(N: int) -> int:
+    """Env-axis block for ring writes: the widest multiple of 128 up to
+    1024 dividing N (the lane rule of the (T, S*N) channels), else N."""
+    for be in (1024, 512, 256, 128):
+        if N % be == 0:
+            return be
+    return N
+
+
+def _kernel(slot_ref, obs_p, act_p, rew_p, done_p, obs_i, act_i, rew_i,
+            done_i, obs_o, act_o, rew_o, done_o):
+    del slot_ref, obs_i, act_i, rew_i, done_i      # aliased to outputs
+    obs_o[...] = obs_p[...]
+    act_o[...] = act_p[...]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():                     # (T, block) columns: once per env block
+        rew_o[...] = rew_p[...]
+        done_o[...] = done_p[...]
 
 
 def pack_channels(bufs: Dict[str, jax.Array], payloads: Dict[str, jax.Array],
@@ -107,33 +115,56 @@ def pack_channels(bufs: Dict[str, jax.Array], payloads: Dict[str, jax.Array],
     ``bufs``/``payloads`` are keyed by ``CHANNELS``; payload shapes are the
     per-push shapes (see module docstring).  ``slot`` is a traced int32 —
     no retrace per slot.
+
+    Grid ``(env blocks, T)``: each step copies one ``(block, dim)`` row of
+    obs/actions into the slot's column block, and the ``(T, block)``
+    reward/done columns once per env block.  The ring inputs stay in HBM
+    (``pl.ANY``), aliased to the outputs, so untouched slots survive and
+    nothing but the written block passes through VMEM.  The one-row
+    ``bootstrap``/``actor_version`` writes are sub-tile rows no block can
+    address; they land by ``dynamic_update_slice`` in the same program.
     """
     pay = _as_payloads(payloads)
     T, N = pay["rewards"].shape
-    slot_arr = jnp.asarray(slot, jnp.int32).reshape(1, 1)
+    be = _env_block(N)
+    nb = N // be
+    slot_arr = jnp.asarray(slot, jnp.int32).reshape(1)
 
-    def full(shape):
-        return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+    def rows(d):
+        return pl.BlockSpec((None, be, d), lambda i, t, s: (t, i, 0))
 
-    in_specs = [pl.BlockSpec((1, 1), lambda i: (0, 0),
-                             memory_space=pltpu.SMEM)]
-    in_specs += [full(pay[c].shape) for c in CHANNELS]
-    in_specs += [full(bufs[c].shape) for c in CHANNELS]
-    out_specs = [full(bufs[c].shape) for c in CHANNELS]
-    out_shape = [jax.ShapeDtypeStruct(bufs[c].shape, bufs[c].dtype)
-                 for c in CHANNELS]
+    def ring_rows(d):
+        return pl.BlockSpec((None, be, d),
+                            lambda i, t, s: (t, s[0] * nb + i, 0))
 
+    cols = pl.BlockSpec((T, be), lambda i, t, s: (0, i))
+    ring_cols = pl.BlockSpec((T, be), lambda i, t, s: (0, s[0] * nb + i))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    O, A = pay["obs"].shape[2], pay["actions"].shape[2]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nb, T),
+        in_specs=[rows(O), rows(A), cols, cols, hbm, hbm, hbm, hbm],
+        out_specs=[ring_rows(O), ring_rows(A), ring_cols, ring_cols],
+    )
+    keys = ("obs", "actions", "rewards", "dones")
     out = pl.pallas_call(
-        functools.partial(_kernel, n_env=N),
-        grid=(1,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        input_output_aliases={1 + len(CHANNELS) + i: i
-                              for i in range(len(CHANNELS))},
+        _kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(bufs[c].shape, bufs[c].dtype)
+                   for c in keys],
+        # alias indices count the scalar-prefetch operand
+        input_output_aliases={5 + k: k for k in range(4)},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(slot_arr, *[pay[c] for c in CHANNELS], *[bufs[c] for c in CHANNELS])
-    return dict(zip(CHANNELS, out))
+    )(slot_arr, *[pay[c] for c in keys], *[bufs[c] for c in keys])
+    out = dict(zip(keys, out))
+    z = jnp.int32(0)
+    s = slot_arr[0]
+    for c in ("bootstrap", "actor_version"):
+        out[c] = jax.lax.dynamic_update_slice(bufs[c], pay[c], (s, z))
+    return out
 
 
 # -------------------------------------------------------------------- xla --

@@ -1,9 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` — the
+On the CPU backend the kernels execute with ``interpret=True`` — the
 kernel body runs in Python per grid cell, validating the exact TPU program
-against the ``ref.py`` oracles.  On TPU backends the same calls compile to
-Mosaic.
+against the ``ref.py`` oracles.  On TPU the same calls compile to Mosaic.
+Any other backend is an error: there is no silent interpret fallback.
 """
 from __future__ import annotations
 
@@ -22,7 +22,14 @@ from repro.kernels.paged_decode import paged_decode_attention as _paged
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend, False on TPU; raises anywhere else."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels here target TPU (compiled) or CPU "
+                       f"(interpret mode); backend {backend!r} is neither")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",

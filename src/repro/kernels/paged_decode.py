@@ -49,19 +49,20 @@ def _kernel(tbl_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, sp_ref, o_ref,
         s = jnp.tanh(s / softcap) * softcap
 
     qpos = pos_ref[b]
-    kpos = sp_ref[0]                                  # (page,)
+    kpos = sp_ref[0]                                  # (1, page)
     valid = (kpos >= 0) & (kpos <= qpos) & (kpos > qpos - win_ref[0])
     valid &= tbl_ref[b, mi] >= 0
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
     l_prev = l_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    # masked entries contribute exactly 0, also when a whole page is
+    # masked (m_new == NEG_INF): unwritten pool slots never leak into acc
+    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + jnp.sum(p, axis=-1)
     v = v_ref[0, 0].astype(jnp.float32)               # (page, hd)
-    v = jnp.where(valid[:, None], v, 0.0)
     acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
         p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
@@ -94,6 +95,10 @@ def paged_decode_attention(q, k_pages, v_pages, slot_pos, table, positions,
     qh = q.reshape(B * KH, G, hd)                     # head h = kh*G + g
     kp = k_pages.transpose(0, 2, 1, 3)                # (N, KH, page, hd)
     vp = v_pages.transpose(0, 2, 1, 3)
+    # one page's positions as a (1, page) row: a (1, page) block of the
+    # (N, page) array would break the TPU rule that the last two block
+    # dims divide by (8, 128) or span the array
+    sp = slot_pos.reshape(N, 1, page)
 
     def page_row(h, m, tbl, pos, w):
         return jnp.maximum(tbl[h // KH, m], 0), h % KH, 0, 0
@@ -107,9 +112,9 @@ def paged_decode_attention(q, k_pages, v_pages, slot_pos, table, positions,
             pl.BlockSpec((1, G, hd), lambda h, m, tbl, pos, w: (h, 0, 0)),
             pl.BlockSpec((1, 1, page, hd), page_row),
             pl.BlockSpec((1, 1, page, hd), page_row),
-            pl.BlockSpec((1, page),
+            pl.BlockSpec((1, 1, page),
                          lambda h, m, tbl, pos, w:
-                         (jnp.maximum(tbl[h // KH, m], 0), 0)),
+                         (jnp.maximum(tbl[h // KH, m], 0), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G, hd), lambda h, m, tbl, pos, w:
                                (h, 0, 0)),
@@ -123,5 +128,5 @@ def paged_decode_attention(q, k_pages, v_pages, slot_pos, table, positions,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KH, G, hd), q.dtype),
         interpret=interpret,
-    )(table, positions, win, qh, kp, vp, slot_pos)
+    )(table, positions, win, qh, kp, vp, sp)
     return out.reshape(B, H, hd)

@@ -43,6 +43,9 @@ def main():
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     cfg = get_reduced(args.arch)
     if cfg.is_encoder_only:
         raise SystemExit(f"{args.arch} is encoder-only: no decode serving "

@@ -102,8 +102,10 @@ def collect_ring(policy_params, env, env_state, obs, key, num_steps: int,
     ``slot`` of the ``{obs, actions, rewards, dones}`` buffers ``bufs``
     — the ``ChannelRing`` layout from ``kernels/channel_pack.py``.
     ``bufs`` is donated; use the returned dict.  On TPU the step is the
-    Pallas megakernel; elsewhere the identically fused XLA program
-    (``mega_step_ring``), matching the ``pack_channels`` convention.
+    compiled Pallas megakernel; on the CPU backend the identically fused
+    XLA program (``mega_step_ring``), matching the ``pack_channels``
+    convention.  ``use_pallas=True`` on the CPU runs the kernel in
+    interpret mode.
 
     Returns ``(bufs, env_state, last_obs, bootstrap, key)`` where
     ``bootstrap`` is the value of ``last_obs`` under ``policy_params``.
@@ -111,15 +113,16 @@ def collect_ring(policy_params, env, env_state, obs, key, num_steps: int,
     if not getattr(env, "megakernel", False):
         raise ValueError("collect_ring needs VectorEnv(megakernel=True); "
                          "use collect for the vmap path")
-    on_tpu = jax.default_backend() == "tpu"
-    use_pallas = on_tpu if use_pallas is None else use_pallas
+    from repro.kernels.ops import _interpret_default
+    interpret = _interpret_default()
+    use_pallas = (not interpret) if use_pallas is None else use_pallas
     mc = env.mega
     return _collect_ring(
         policy_params, env_state, obs, key, bufs, jnp.asarray(slot, jnp.int32),
         mc.sensor, mc.tgt, mc.masses, mc.lengths, chain=mc.chain,
         task=mc.task, substeps=env.spec.substeps, dt=env.spec.dt,
         max_episode_len=env.spec.max_episode_len, num_steps=int(num_steps),
-        use_pallas=use_pallas, interpret=not on_tpu, policy_fn=policy_fn)
+        use_pallas=use_pallas, interpret=interpret, policy_fn=policy_fn)
 
 
 def gae(rewards, values, dones, last_value, gamma: float = 0.99,

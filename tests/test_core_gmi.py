@@ -149,7 +149,7 @@ def test_profiler_distinguishes_oom_from_genuine_bugs(monkeypatch):
         raise FakeOOM("RESOURCE_EXHAUSTED: out of memory allocating arena")
 
     monkeypatch.setattr("repro.rl.ppo.init_train", boom_oom)
-    prof = make_ppo_profiler(iters=1)("BallBalance", 1, 128)
+    prof = make_ppo_profiler(iters=1, mem_budget_bytes=32e9)("BallBalance", 1, 128)
     assert not prof.runnable and prof.memory > 0
 
     def boom_bug(*a, **k):
@@ -157,7 +157,7 @@ def test_profiler_distinguishes_oom_from_genuine_bugs(monkeypatch):
 
     monkeypatch.setattr("repro.rl.ppo.init_train", boom_bug)
     with pytest.raises(ValueError):
-        make_ppo_profiler(iters=1)("BallBalance", 1, 128)
+        make_ppo_profiler(iters=1, mem_budget_bytes=32e9)("BallBalance", 1, 128)
 
 
 def test_instance_mesh_multi_device_keeps_all_chips():

@@ -15,6 +15,7 @@ def test_multidevice_suite():
     script = os.path.join(os.path.dirname(__file__), "_multidev_checks.py")
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, script], env=env,
                           capture_output=True, text=True, timeout=900)
     sys.stdout.write(proc.stdout)

@@ -65,3 +65,36 @@ def test_episode_cap_triggers_done():
     assert bool(done.all())
     # auto-reset: t back near zero
     assert int(state2.t.max()) <= 1
+
+
+_SENSOR_SEEDS = {"Ant": 571461097, "Anymal": 1025405366,
+                 "BallBalance": 414204116, "FrankaCabinet": 1481782954,
+                 "Humanoid": 1470153286, "ShadowHand": 569249199}
+
+
+@pytest.mark.parametrize("name", all_env_names())
+def test_sensor_seed_is_pinned(name):
+    """The sensor projection is seeded from a CRC-32 of the env name, a
+    value fixed across processes and Python versions (``hash`` of a str
+    is salted per process)."""
+    from repro.envs.suite import sensor_seed
+    assert sensor_seed(name) == _SENSOR_SEEDS[name]
+
+
+def test_sensor_matrix_same_in_another_process():
+    """A child with a different hash salt builds the identical sensor
+    matrix, so the env — and the compiled vmap path, where the matrix is
+    a constant — is the same in every process."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = ("import numpy as np; from repro.envs import make_env; "
+            "print(np.asarray(make_env('Humanoid').mega.sensor)"
+            ".tobytes().hex())")
+    env = dict(os.environ, PYTHONHASHSEED="12345", JAX_PLATFORMS="cpu",
+               PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True, timeout=300)
+    here = np.asarray(make_env("Humanoid").mega.sensor).tobytes().hex()
+    assert out.stdout.strip().splitlines()[-1] == here

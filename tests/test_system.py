@@ -81,7 +81,7 @@ def test_async_a3c_over_channel_pipeline():
 def test_selection_with_real_profiler_tiny():
     """Algorithm 2 with the real PPO profiler on a tiny search space."""
     from repro.core.selection import explore, make_ppo_profiler
-    profile = make_ppo_profiler(iters=1)
+    profile = make_ppo_profiler(iters=1, mem_budget_bytes=32e9)
     trace = explore(profile, "BallBalance", num_gpu=1,
                     gmi_per_gpu_range=(2, 1), num_env_sweep=(128, 256))
     ne, gpg = trace.best_config
