@@ -1,5 +1,6 @@
 """Pallas kernel microbenchmarks (interpret mode on CPU: numbers validate
-CORRECTNESS cost only; TPU timings come from the roofline, not this host).
+CORRECTNESS cost only; TPU timings come from the
+benchmark on the chip (bench/run.py), not this host).
 Compares kernel vs pure-jnp oracle per call."""
 from __future__ import annotations
 

@@ -19,7 +19,6 @@
 #   bench_disagg         — disaggregated prefill/decode serving: migrated
 #                          vs local path, tok/s per role, migrate-vs-local
 #                          crossover from measured Table-2 terms
-#   roofline             — §Roofline terms from the dry-run artifacts
 #
 # Every invocation starts with the repro.analysis static pre-flight
 # (python -m repro.analysis --strict): a tree with findings — tracked
@@ -120,7 +119,7 @@ def main() -> None:
                             bench_disagg, bench_faults, bench_kernels,
                             bench_lgr, bench_mcc, bench_num_env,
                             bench_reward, bench_selection, bench_serving,
-                            bench_sync_training, roofline)
+                            bench_sync_training)
     from benchmarks.common import ROWS, emit
 
     findings = _analysis_findings(_ROOT)
@@ -172,7 +171,6 @@ def main() -> None:
         ("kernels", bench_kernels.run),
         ("faults", bench_faults.run),
         ("disagg", disagg_suite),
-        ("roofline", roofline.run),
     ]
     flags = {"--quick", "--strict"}
     args = [a for a in sys.argv[1:] if a not in flags]

@@ -89,6 +89,7 @@ from repro.kernels.channel_pack import (CHANNELS, alloc_rings,
                                         pack_generation,
                                         unpack_cache_payload)
 from repro.rl.a3c import Experience
+from repro.spans import span
 
 
 @dataclass
@@ -531,7 +532,23 @@ class MultiChannelPipeline:
         materialize while pushes kept landing in the front halves.  The
         first flush returns ``{}``; :meth:`drain` delivers the tail.
         """
-        t0 = time.perf_counter()
+        with span("mcc.flush") as timed:
+            out, nbytes = self._route()
+        if nbytes > 0:
+            # one (seconds, bytes) sample per delivering flush — the live
+            # channel-transfer evidence the bandwidth calibrator consumes
+            # (the seconds are the span's: host time to snapshot, route
+            # and batch, not a finished transfer; overlap mode undercounts
+            # further: the back generation materialized during the
+            # previous round, which is why the calibrator down-weights
+            # transfer rows relative to reduce rows)
+            self._transfer_samples.append((timed.seconds, nbytes))
+            del self._transfer_samples[:-64]
+        return out
+
+    def _route(self) -> Tuple[Dict[int, List[Experience]], int]:
+        """:meth:`flush`'s work; returns the routed batches and the bytes
+        they carry."""
         current: List[Tuple[int, Dict[str, jax.Array]]] = []
         for gkey, snaps in self._pending.items():
             current.extend((gkey, ch) for ch in snaps)
@@ -560,7 +577,7 @@ class MultiChannelPipeline:
                     kept.append((gkey, ch))
             groups = kept
         if not groups:
-            return {}
+            return {}, 0
         bytes_before = self.compressor.stats.total_bytes
         self.compressor.record_flush([ch for _, ch in groups])
         out: Dict[int, List[Experience]] = {}
@@ -569,17 +586,7 @@ class MultiChannelPipeline:
                 ch, agent_gpu=None if gkey == -1 else gkey)
             out.setdefault(dst, []).extend(self.batchers[dst].prepare(ch))
             self.delivered_samples += int(np.prod(ch["rewards"].shape))
-        nbytes = self.compressor.stats.total_bytes - bytes_before
-        if nbytes > 0:
-            # one (seconds, bytes) sample per delivering flush — the live
-            # channel-transfer evidence the bandwidth calibrator consumes
-            # (overlap mode undercounts: the back generation materialized
-            # during the previous round, which is why the calibrator
-            # down-weights transfer rows relative to reduce rows)
-            self._transfer_samples.append(
-                (time.perf_counter() - t0, int(nbytes)))
-            del self._transfer_samples[:-64]
-        return out
+        return out, self.compressor.stats.total_bytes - bytes_before
 
     def take_transfer_samples(self) -> List[Tuple[float, int]]:
         """Per-flush (seconds, bytes) channel-transfer timings since the

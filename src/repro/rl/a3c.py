@@ -9,7 +9,6 @@ policy; trainers consume experience batches in arrival order.
 """
 from __future__ import annotations
 
-import time
 from typing import NamedTuple
 
 import jax
@@ -18,6 +17,7 @@ import jax.numpy as jnp
 from repro.models.policy import entropy, log_prob, policy_apply
 from repro.optim import adam_update
 from repro.rl.rollout import collect, collect_ring
+from repro.spans import span
 
 
 class Experience(NamedTuple):
@@ -208,47 +208,88 @@ class AsyncRunner:
     # repro: hot
     def _train(self, routed):
         """Consume routed trainer batches; returns (losses, staleness)."""
-        losses, stale = [], []
-        # a mesh-less communicator's sync closure is the identity (and is
-        # deliberately NOT timed: measured reduce seconds enter through
-        # RoundSample.reduce_s / Communicator.observe, never from no-ops)
-        sync = None if self.communicator is None \
-            else self.communicator.grad_sync_fn
-        # flat worklist so a mid-iteration trainer fault can re-queue the
-        # failing batch AND everything not yet consumed
-        work = [(dst, exp) for dst, batches in routed.items()
-                for exp in batches]
-        for i, (dst, exp) in enumerate(work):
-            if self.fault_hook is not None:
-                try:
-                    self.fault_hook("trainer", dst)
-                except BaseException:
-                    # spill, not drop: this batch's gradient is lost with
-                    # the trainer, but its experience — and every batch
-                    # behind it — rejoins the pipeline for the survivors
-                    self.pipe.requeue([e for _, e in work[i:]])
-                    raise
-            stale.append(int(staleness(self.version, exp)))
-            new_params, new_opt, loss = trainer_update(
-                self.params, self.opt_state, exp, lr=self.lr,
-                grad_sync_fn=sync,
-                use_fused_kernels=self.use_fused_kernels)
-            if self.nonfinite_guard and not bool(jnp.isfinite(loss)):
-                # discard the poisoned update: the pre-update pytrees are
-                # still live (JAX arrays are immutable — rollback is free);
-                # version stays put so staleness accounting is untouched
-                self.poisoned_batches += 1
-                self.poisoned_samples += int(exp.rewards.size)
-                continue
-            self.params, self.opt_state = new_params, new_opt
-            # keep the loss on device: a float() here would sync the
-            # trainer stream once per batch (host-sync-in-hot-path)
-            losses.append(loss)
-            self.trained_samples += int(exp.rewards.size)
-            self.version = self.version + 1
-        # single post-loop drain of the queued losses
-        return ([float(x)  # repro: allow(host-sync-in-hot-path)
-                 for x in jax.device_get(losses)], stale)
+        with span("a3c.train"):
+            losses, stale = [], []
+            # a mesh-less communicator's sync closure is the identity (and
+            # is deliberately NOT timed: measured reduce seconds enter
+            # through RoundSample.reduce_s / Communicator.observe, never
+            # from no-ops)
+            sync = None if self.communicator is None \
+                else self.communicator.grad_sync_fn
+            # flat worklist so a mid-iteration trainer fault can re-queue
+            # the failing batch AND everything not yet consumed
+            work = [(dst, exp) for dst, batches in routed.items()
+                    for exp in batches]
+            for i, (dst, exp) in enumerate(work):
+                if self.fault_hook is not None:
+                    try:
+                        self.fault_hook("trainer", dst)
+                    except BaseException:
+                        # spill, not drop: this batch's gradient is lost
+                        # with the trainer, but its experience — and every
+                        # batch behind it — rejoins the pipeline for the
+                        # survivors
+                        self.pipe.requeue([e for _, e in work[i:]])
+                        raise
+                with span("host_read"):
+                    stale.append(int(staleness(self.version, exp)))
+                with span("a3c.update"):
+                    new_params, new_opt, loss = trainer_update(
+                        self.params, self.opt_state, exp, lr=self.lr,
+                        grad_sync_fn=sync,
+                        use_fused_kernels=self.use_fused_kernels)
+                if self.nonfinite_guard:
+                    with span("host_read"):
+                        finite = bool(jnp.isfinite(loss))
+                    if not finite:
+                        # discard the poisoned update: the pre-update
+                        # pytrees are still live (JAX arrays are immutable —
+                        # rollback is free); version stays put so staleness
+                        # accounting is untouched
+                        self.poisoned_batches += 1
+                        self.poisoned_samples += int(exp.rewards.size)
+                        continue
+                self.params, self.opt_state = new_params, new_opt
+                # keep the loss on device: a float() here would sync the
+                # trainer stream once per batch (host-sync-in-hot-path)
+                losses.append(loss)
+                self.trained_samples += int(exp.rewards.size)
+                self.version = self.version + 1
+            if not losses:
+                return [], stale
+            # single post-loop drain of the queued losses
+            with span("host_read"):
+                losses = jax.device_get(losses)
+            return [float(x)  # repro: allow(host-sync-in-hot-path)
+                    for x in losses], stale
+
+    # repro: hot
+    def _serve(self, a, direct):
+        """Serving GMI ``a`` rolls its envs for one round into the
+        pipeline."""
+        es, obs, k = self.actors[a]
+        if direct:
+            carry = {}
+
+            def producer(bufs, slot):
+                bufs, es2, obs2, boot, k2 = collect_ring(
+                    self.actor_params, self.env, es, obs, k,
+                    self.num_steps, bufs, slot)
+                carry["actor"] = [es2, obs2, k2]
+                return bufs, boot, self.version
+
+            self.pipe.produce(a, self.num_steps, self.num_envs,
+                              self.env.spec.obs_dim,
+                              self.env.spec.act_dim, producer)
+            self.actors[a] = carry["actor"]
+            self.predictions += self.num_steps * self.num_envs
+            return
+        exp, es, obs, k = actor_collect(
+            self.actor_params, self.version, self.env, es, obs, k,
+            self.num_steps)
+        self.actors[a] = [es, obs, k]
+        self.predictions += int(exp.rewards.size)
+        self.pipe.push(a, exp)
 
     # repro: hot
     def round(self):
@@ -256,51 +297,30 @@ class AsyncRunner:
 
         With overlap on, the trained batches are the previous round's
         flush (the first round returns no losses)."""
-        # round-duration telemetry feeds the controller's ladder
-        t0 = time.perf_counter()  # repro: allow(host-sync-in-hot-path)
         # megakernel envs on blocking rings produce experience straight
         # into the ring slot (collect_ring): no staged Trajectory, no
         # pack_channels re-copy.  Overlap rings stage references (zero
         # producer-side device work already), so they keep actor_collect.
         direct = (getattr(self.env, "megakernel", False)
                   and not self.overlap and hasattr(self.pipe, "produce"))
-        for a in self.serving_gmis:
-            if self.fault_hook is not None:
-                # a kill here loses only THIS GMI's not-yet-collected
-                # round; earlier actors' pushes are already ringed and
-                # survive into the recovery drain
-                self.fault_hook("serving", a)
-            es, obs, k = self.actors[a]
-            if direct:
-                carry = {}
-
-                def producer(bufs, slot, _es=es, _obs=obs, _k=k):
-                    bufs, es2, obs2, boot, k2 = collect_ring(
-                        self.actor_params, self.env, _es, _obs, _k,
-                        self.num_steps, bufs, slot)
-                    carry["actor"] = [es2, obs2, k2]
-                    return bufs, boot, self.version
-
-                self.pipe.produce(a, self.num_steps, self.num_envs,
-                                  self.env.spec.obs_dim,
-                                  self.env.spec.act_dim, producer)
-                self.actors[a] = carry["actor"]
-                self.predictions += self.num_steps * self.num_envs
-                continue
-            exp, es, obs, k = actor_collect(
-                self.actor_params, self.version, self.env, es, obs, k,
-                self.num_steps)
-            self.actors[a] = [es, obs, k]
-            self.predictions += int(exp.rewards.size)
-            self.pipe.push(a, exp)
         before = self.trained_samples
-        losses, stale = self._train(self.pipe.flush())
-        self.actor_params = self.params        # model push AFTER acting
+        # the round span's duration is the round time the controller's
+        # ladder observes
+        with span("a3c.round", round=self.rounds) as timed:
+            for a in self.serving_gmis:
+                if self.fault_hook is not None:
+                    # a kill here loses only THIS GMI's not-yet-collected
+                    # round; earlier actors' pushes are already ringed and
+                    # survive into the recovery drain
+                    self.fault_hook("serving", a)
+                with span("a3c.serve", gmi=a):
+                    self._serve(a, direct)
+            losses, stale = self._train(self.pipe.flush())
+            self.actor_params = self.params    # model push AFTER acting
         if self.controller is not None:
             decision = self.controller.observe_pipeline(
                 self.pipe, samples=self.trained_samples - before,
-                # repro: allow(host-sync-in-hot-path)
-                dt=time.perf_counter() - t0)
+                dt=timed.seconds)
             if decision is not None:
                 if decision.layout_changed:
                     self.replan(decision)

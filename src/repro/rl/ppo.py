@@ -61,15 +61,20 @@ def train_iteration(params, opt_state: AdamState, env, env_state, obs, key,
     key, metrics).  ``grad_sync_fn`` may be a closure or a Communicator."""
     from repro.comm.api import as_grad_sync   # lazy: rl <-> comm layering
     grad_sync_fn = as_grad_sync(grad_sync_fn)
-    traj, env_state, obs, last_value, key = collect(
-        params, env, env_state, obs, key, cfg.num_steps, policy_fn)
-    if cfg.use_fused_kernels:
-        # fused Pallas kernel: advantages arrive normalized over the batch
-        advs, returns = gae_fused(traj.rewards, traj.values, traj.dones,
-                                  last_value, cfg.gamma, cfg.lam)
-    else:
-        advs, returns = gae(traj.rewards, traj.values, traj.dones,
-                            last_value, cfg.gamma, cfg.lam)
+    # named scopes label the step's device ops by phase in a profile
+    with jax.named_scope("ppo/collect"):
+        traj, env_state, obs, last_value, key = collect(
+            params, env, env_state, obs, key, cfg.num_steps, policy_fn)
+    with jax.named_scope("ppo/gae"):
+        if cfg.use_fused_kernels:
+            # fused Pallas kernel: advantages arrive normalized over the
+            # batch
+            advs, returns = gae_fused(traj.rewards, traj.values,
+                                      traj.dones, last_value, cfg.gamma,
+                                      cfg.lam)
+        else:
+            advs, returns = gae(traj.rewards, traj.values, traj.dones,
+                                last_value, cfg.gamma, cfg.lam)
 
     T, N = traj.rewards.shape
     flat = jax.tree.map(lambda x: x.reshape((T * N,) + x.shape[2:]),
@@ -79,30 +84,33 @@ def train_iteration(params, opt_state: AdamState, env, env_state, obs, key,
 
     def epoch(carry, _):
         params, opt_state, key = carry
-        key, pkey = jax.random.split(key)
-        perm = jax.random.permutation(pkey, T * N)
-        if cfg.use_fused_kernels:
-            # single gather straight into minibatch layout — no
-            # shuffle-then-reshape copy chain through XLA
-            idx = perm.reshape((cfg.num_minibatches, mb_size))
-            mb = jax.tree.map(lambda x: jnp.take(x, idx, axis=0), flat)
-        else:
-            shuf = jax.tree.map(lambda x: x[perm], flat)
-            mb = jax.tree.map(
-                lambda x: x.reshape((cfg.num_minibatches, mb_size)
-                                    + x.shape[1:]), shuf)
+        with jax.named_scope("ppo/shuffle"):
+            key, pkey = jax.random.split(key)
+            perm = jax.random.permutation(pkey, T * N)
+            if cfg.use_fused_kernels:
+                # single gather straight into minibatch layout — no
+                # shuffle-then-reshape copy chain through XLA
+                idx = perm.reshape((cfg.num_minibatches, mb_size))
+                mb = jax.tree.map(lambda x: jnp.take(x, idx, axis=0), flat)
+            else:
+                shuf = jax.tree.map(lambda x: x[perm], flat)
+                mb = jax.tree.map(
+                    lambda x: x.reshape((cfg.num_minibatches, mb_size)
+                                        + x.shape[1:]), shuf)
 
         def minibatch(carry, batch):
             params, opt_state = carry
-            (loss, aux), grads = jax.value_and_grad(
-                ppo_loss, has_aux=True)(params, batch, cfg.clip_eps,
-                                        cfg.vf_coef, cfg.ent_coef, policy_fn,
-                                        not cfg.use_fused_kernels)
+            with jax.named_scope("ppo/loss_grad"):
+                (loss, aux), grads = jax.value_and_grad(
+                    ppo_loss, has_aux=True)(
+                        params, batch, cfg.clip_eps, cfg.vf_coef,
+                        cfg.ent_coef, policy_fn, not cfg.use_fused_kernels)
             if grad_sync_fn is not None:
                 grads = grad_sync_fn(grads)
-            params, opt_state = adam_update(
-                grads, opt_state, params, lr=cfg.lr, beta1=0.9, beta2=0.999,
-                grad_clip=cfg.max_grad_norm)
+            with jax.named_scope("ppo/adam"):
+                params, opt_state = adam_update(
+                    grads, opt_state, params, lr=cfg.lr, beta1=0.9,
+                    beta2=0.999, grad_clip=cfg.max_grad_norm)
             return (params, opt_state), loss
 
         (params, opt_state), losses = jax.lax.scan(minibatch,

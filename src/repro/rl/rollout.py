@@ -41,10 +41,12 @@ def collect(policy_params, env, env_state, obs, key, num_steps: int,
     def step(carry, _):
         env_state, obs, key = carry
         key, akey = jax.random.split(key)
-        mu, log_std, value = policy_fn(policy_params, obs)
-        action = sample_action(akey, mu, log_std)
-        lp = log_prob(mu, log_std, action)
-        env_state, next_obs, reward, done = env.step(env_state, action)
+        with jax.named_scope("policy"):
+            mu, log_std, value = policy_fn(policy_params, obs)
+            action = sample_action(akey, mu, log_std)
+            lp = log_prob(mu, log_std, action)
+        with jax.named_scope("env_step"):
+            env_state, next_obs, reward, done = env.step(env_state, action)
         out = (obs, action, lp, reward, done.astype(jnp.float32), value)
         return (env_state, next_obs, key), out
 
@@ -63,24 +65,31 @@ def _collect_ring(params, state, obs, key, bufs, slot, sensor, tgt, masses,
                   lengths, *, chain, task, substeps, dt, max_episode_len,
                   num_steps, use_pallas, interpret, policy_fn):
     from repro.envs.base import EnvState
-    from repro.kernels.env_megakernel import env_mega_step, mega_step_ring
+    from repro.kernels import ops
+    from repro.kernels.env_megakernel import mega_step_ring
     slot_i = jnp.asarray(slot, jnp.int32)
 
     def step(carry, step_t):
         state, obs, key, bufs = carry
         key, akey = jax.random.split(key)
-        mu, log_std, _ = policy_fn(params, obs)
-        action = sample_action(akey, mu, log_std)
-        if use_pallas:
-            out = env_mega_step(
-                *state, action, obs, bufs, step_t, slot_i, sensor, tgt,
-                masses, lengths, chain=chain, task=task, substeps=substeps,
-                dt=dt, max_episode_len=max_episode_len, interpret=interpret)
-        else:
-            out = mega_step_ring(
-                *state, action, obs, bufs, step_t, slot_i, sensor, tgt,
-                masses, lengths, chain=chain, task=task, substeps=substeps,
-                dt=dt, max_episode_len=max_episode_len)
+        with jax.named_scope("policy"):
+            mu, log_std, _ = policy_fn(params, obs)
+            action = sample_action(akey, mu, log_std)
+        with jax.named_scope("env_mega_step"):
+            if use_pallas:
+                # the jitted wrapper names the kernel's instruction
+                # env_mega_step in the compiled program
+                out = ops.env_mega_step(
+                    *state, action, obs, bufs, step_t, slot_i, sensor, tgt,
+                    masses, lengths, chain=chain, task=task,
+                    substeps=substeps, dt=dt,
+                    max_episode_len=max_episode_len, interpret=interpret)
+            else:
+                out = mega_step_ring(
+                    *state, action, obs, bufs, step_t, slot_i, sensor, tgt,
+                    masses, lengths, chain=chain, task=task,
+                    substeps=substeps, dt=dt,
+                    max_episode_len=max_episode_len)
         q, qd, root, pa, t, seed, resets, next_obs = out[:8]
         return (EnvState(q, qd, root, pa, t, seed, resets), next_obs, key,
                 out[10]), None

@@ -12,6 +12,7 @@ the worker running this file loads the TPU compiler.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -116,9 +117,79 @@ def _paged_attention(s):
         _sds((B, M), i32, s), _sds((B,), i32, s), interpret=False)
 
 
+def _pallas_names(text):
+    """Instruction names of the compiled Pallas kernels in ``text``."""
+    return re.findall(r"^\s*(?:ROOT )?%(\S+) = .*tpu_custom_call", text,
+                      re.MULTILINE)
+
+
 @pytest.mark.parametrize("lower", [_env_mega_step, _pack_channels, _gae_norm,
                                    _nstep_returns, _paged_attention],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(one_chip, lower):
     compiled = lower(one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # the eager A3C trainer runs ops.nstep_returns as this very program
+    names = _pallas_names(compiled.as_text())
+    assert names and all(n.startswith(lower.__name__.lstrip("_"))
+                         for n in names), names
+
+
+# the programs in which the benchmark's kernel metrics find their kernel
+# by its instruction name (bench/metrics/*.py)
+def _collect_ring(s, N=1024):
+    from repro.models.policy import init_policy, policy_apply
+    from repro.rl import rollout
+    env = make_env("Ant", megakernel=True)
+    mc, spec = env.mega, env.spec
+
+    def sds(x):
+        return _sds(x.shape, x.dtype, s)
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: init_policy(jax.random.key(0), spec.policy_dims)))
+    es, obs = jax.tree.map(sds, jax.eval_shape(
+        lambda: env.reset(jax.random.PRNGKey(0), N)))
+    key = sds(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    ring = {"obs": (T, SLOTS * N, spec.obs_dim),
+            "actions": (T, SLOTS * N, spec.act_dim),
+            "rewards": (T, SLOTS * N), "dones": (T, SLOTS * N)}
+    bufs = {k: _sds(v, jnp.float32, s) for k, v in ring.items()}
+    return rollout._collect_ring.lower(
+        params, es, obs, key, bufs, _sds((), jnp.int32, s),
+        *map(sds, (mc.sensor, mc.tgt, mc.masses, mc.lengths)),
+        chain=mc.chain, task=mc.task, substeps=spec.substeps, dt=spec.dt,
+        max_episode_len=spec.max_episode_len, num_steps=T, use_pallas=True,
+        interpret=False, policy_fn=policy_apply)
+
+
+def _ppo_step(s, N=1024):
+    from repro.rl.ppo import PPOConfig, init_train, make_train_step
+    env = make_env("Ant")
+    step = make_train_step(env, PPOConfig(num_steps=T, num_epochs=1,
+                                          use_fused_kernels=True))
+    state = jax.eval_shape(lambda: init_train(
+        jax.random.PRNGKey(0), env, env.spec.policy_dims, N))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(1))
+    return step.lower(*jax.tree.map(lambda x: _sds(x.shape, x.dtype, s),
+                                    (*state, key)))
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Kernels called without ``interpret`` compile for the described
+    chip; traces cached either side of the patch are dropped."""
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    yield
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("lower,kernel", [(_collect_ring, "env_mega_step"),
+                                          (_ppo_step, "gae_norm")],
+                         ids=["collect_ring", "ppo_step"])
+def test_pallas_instruction_names_in_their_programs(one_chip,
+                                                    compiled_kernels,
+                                                    lower, kernel):
+    names = _pallas_names(lower(one_chip).compile().as_text())
+    assert len(names) == 1 and names[0].startswith(kernel), names
