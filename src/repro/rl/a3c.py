@@ -60,15 +60,25 @@ def nstep_returns(rewards, dones, bootstrap, gamma: float = 0.99, *,
 
 def a3c_loss(params, exp: Experience, gamma: float, vf_coef: float,
              ent_coef: float, use_fused_kernels: bool = False):
+    """The A3C loss and its gradient in ``params``:
+    ``((loss, (pg, vf, ent)), grads)``.
+
+    The n-step returns depend on the experience alone, so they are
+    computed before the gradient is traced: under a jit the returns
+    kernel stays its own ``nstep_returns`` call on HBM operands instead
+    of a JVP of it."""
     rets = nstep_returns(exp.rewards, exp.dones, exp.bootstrap, gamma,
                          use_fused_kernels=use_fused_kernels)
-    mu, log_std, value = policy_apply(params, exp.obs)
-    adv = rets - value
-    lp = log_prob(mu, log_std, exp.actions)
-    pg = -(lp * jax.lax.stop_gradient(adv)).mean()
-    vf = 0.5 * jnp.square(adv).mean()
-    ent = entropy(log_std).mean()
-    return pg + vf_coef * vf - ent_coef * ent, (pg, vf, ent)
+
+    def loss(p):
+        mu, log_std, value = policy_apply(p, exp.obs)
+        adv = rets - value
+        lp = log_prob(mu, log_std, exp.actions)
+        pg = -(lp * jax.lax.stop_gradient(adv)).mean()
+        vf = 0.5 * jnp.square(adv).mean()
+        ent = entropy(log_std).mean()
+        return pg + vf_coef * vf - ent_coef * ent, (pg, vf, ent)
+    return jax.value_and_grad(loss, has_aux=True)(params)
 
 
 def trainer_update(params, opt_state, exp: Experience, *, lr=3e-4,
@@ -80,8 +90,8 @@ def trainer_update(params, opt_state, exp: Experience, *, lr=3e-4,
     ``repro.comm.Communicator`` (resolved via its grad-sync property)."""
     from repro.comm.api import as_grad_sync
     grad_sync_fn = as_grad_sync(grad_sync_fn)
-    (loss, aux), grads = jax.value_and_grad(a3c_loss, has_aux=True)(
-        params, exp, gamma, vf_coef, ent_coef, use_fused_kernels)
+    (loss, aux), grads = a3c_loss(params, exp, gamma, vf_coef, ent_coef,
+                                  use_fused_kernels)
     if grad_sync_fn is not None:
         grads = grad_sync_fn(grads)
     params, opt_state = adam_update(grads, opt_state, params, lr=lr,
@@ -123,14 +133,17 @@ class AsyncRunner:
     An attached :class:`~repro.comm.Communicator` owns the reduction
     decision state for the controller loop: measured per-round reduce
     seconds reach it through ``RoundSample.reduce_s`` (or direct
-    ``observe`` calls from a real SPMD launcher — the runner's eager
-    simulation has no cross-instance reduce to time, and timing the
-    identity closure would feed scheduler noise into the switch
-    hysteresis), and a controller Decision carrying a
-    ``reduction_strategy`` switches the schedule in place — communication
-    plumbing only, params/optimizer untouched.  Mesh-attached
-    communicators are rejected: their sync closure is SPMD-only and
-    cannot run inside this eager trainer.
+    ``observe`` calls from a real SPMD launcher — the runner has no
+    cross-instance reduce to time, and timing the identity closure would
+    feed scheduler noise into the switch hysteresis), and a controller
+    Decision carrying a ``reduction_strategy`` switches the schedule in
+    place — communication plumbing only, params/optimizer untouched.
+    Mesh-attached communicators are rejected: their sync closure is
+    SPMD-only and cannot run inside this one-device trainer.
+
+    Each batch's update is one compiled program (:func:`trainer_update`
+    under ``jax.jit``), traced once per batch shape; ``update_traces``
+    counts the traces.
     """
 
     def __init__(self, env, serving_gmis, trainer_gmis, *, gmi_gpu=None,
@@ -146,7 +159,6 @@ class AsyncRunner:
         self.num_steps = num_steps
         self.num_envs = num_envs
         self.serving_gmis = list(serving_gmis)
-        self.lr = lr
         self.seed = seed
         self.overlap = overlap
         self.controller = controller
@@ -161,12 +173,11 @@ class AsyncRunner:
         self.router = router
         if communicator is not None and communicator.mesh is not None:
             raise TypeError(
-                "AsyncRunner's round-interleaved trainer is eager; a "
-                "mesh-attached Communicator's sync closure is SPMD-only "
-                "(use allreduce in a shard_map launcher, or attach a "
-                "mesh-less Communicator for decision state)")
+                "AsyncRunner's round-interleaved trainer runs on one "
+                "device; a mesh-attached Communicator's sync closure is "
+                "SPMD-only (use allreduce in a shard_map launcher, or "
+                "attach a mesh-less Communicator for decision state)")
         self.communicator = communicator
-        self.use_fused_kernels = use_fused_kernels
         if controller is not None and communicator is not None \
                 and controller.communicator is None:
             controller.communicator = communicator
@@ -196,6 +207,18 @@ class AsyncRunner:
         self.nonfinite_guard = False
         self.poisoned_batches = 0
         self.poisoned_samples = 0
+        # one compiled program per batch update.  Built per runner, and
+        # trainer_update is looked up when traced, so a patched
+        # trainer_update is the one compiled.  It takes no sync closure:
+        # a mesh-less communicator's is the identity.  Nothing is
+        # donated: the non-finite guard's rollback needs the old pytrees.
+        self.update_traces = 0
+
+        def update(params, opt_state, exp):
+            self.update_traces += 1
+            return trainer_update(params, opt_state, exp, lr=lr,
+                                  use_fused_kernels=use_fused_kernels)
+        self._update = jax.jit(update)
 
     def _reset_actors(self):
         self.actors = {}
@@ -210,12 +233,6 @@ class AsyncRunner:
         """Consume routed trainer batches; returns (losses, staleness)."""
         with span("a3c.train"):
             losses, stale = [], []
-            # a mesh-less communicator's sync closure is the identity (and
-            # is deliberately NOT timed: measured reduce seconds enter
-            # through RoundSample.reduce_s / Communicator.observe, never
-            # from no-ops)
-            sync = None if self.communicator is None \
-                else self.communicator.grad_sync_fn
             # flat worklist so a mid-iteration trainer fault can re-queue
             # the failing batch AND everything not yet consumed
             work = [(dst, exp) for dst, batches in routed.items()
@@ -234,10 +251,8 @@ class AsyncRunner:
                 with span("host_read"):
                     stale.append(int(staleness(self.version, exp)))
                 with span("a3c.update"):
-                    new_params, new_opt, loss = trainer_update(
-                        self.params, self.opt_state, exp, lr=self.lr,
-                        grad_sync_fn=sync,
-                        use_fused_kernels=self.use_fused_kernels)
+                    new_params, new_opt, loss = self._update(
+                        self.params, self.opt_state, exp)
                 if self.nonfinite_guard:
                     with span("host_read"):
                         finite = bool(jnp.isfinite(loss))

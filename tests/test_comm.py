@@ -696,7 +696,7 @@ def test_async_runner_replan_switches_strategy_keeps_model_state():
 
 
 def test_async_runner_communicator_contract():
-    """The eager runner never times the mesh-less identity closure into
+    """The runner never times the mesh-less identity closure into
     the switch hysteresis (measured reduce seconds only enter through
     RoundSample.reduce_s / direct observe), and rejects mesh-attached
     communicators outright — their sync closure is SPMD-only."""

@@ -132,6 +132,62 @@ def test_async_runner_over_ring_pipeline():
     assert runner.pipe.migrator.load[100] == runner.pipe.migrator.load[101]
 
 
+@pytest.mark.parametrize("megakernel", [False, True],
+                         ids=["collect", "collect_ring"])
+def test_async_runner_traces_its_update_once_at_a_fixed_shape(megakernel):
+    """Every round's batches reuse the runner's one compiled update."""
+    from repro.core.placement import plan_async
+    from repro.launch.steps import make_async_runner
+    layout = plan_async(2, 1, 2, devices=list(range(4)), devices_per_gpu=2)
+    runner = make_async_runner(make_env("Ant"), layout, num_envs=16,
+                               num_steps=8, megakernel=megakernel)
+    for _ in range(3):
+        ls, _ = runner.round()
+        assert ls and all(np.isfinite(ls))
+    assert runner.update_traces == 1
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["scan", "fused"])
+def test_async_runner_compiled_update_matches_eager_trainer_update(fused):
+    from repro.rl.a3c import AsyncRunner, actor_collect, trainer_update
+    env = make_env("Ant")
+    runner = AsyncRunner(env, [0], [100], num_envs=16, num_steps=8,
+                         lr=1e-3, use_fused_kernels=fused)
+    es, obs = env.reset(jax.random.PRNGKey(3), num_envs=16)
+    exp, *_ = actor_collect(runner.params, runner.version, env, es, obs,
+                            jax.random.PRNGKey(4), 8)
+    got = runner._update(runner.params, runner.opt_state, exp)
+    want = trainer_update(runner.params, runner.opt_state, exp,
+                          lr=1e-3, use_fused_kernels=fused)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-6)
+    assert runner.update_traces == 1
+
+
+def test_async_runner_replan_to_new_num_envs_traces_once_more():
+    from repro.core.controller import Decision
+    from repro.core.placement import plan_async
+    from repro.launch.steps import make_async_runner
+    layout = plan_async(2, 1, 2, devices=list(range(4)), devices_per_gpu=2)
+    runner = make_async_runner(make_env("Ant"), layout, num_envs=16,
+                               num_steps=8)
+    runner.layout_builder = lambda d: plan_async(
+        2, d.serving_gpus, d.gmi_per_gpu, devices=list(range(4)),
+        devices_per_gpu=2)
+    runner.round()
+    runner.round()
+    assert runner.update_traces == 1
+    runner.replan(Decision(num_env=8, gmi_per_gpu=2, serving_gpus=1,
+                           reason="test"))
+    assert runner.num_envs == 8
+    for _ in range(2):
+        ls, _ = runner.round()
+        assert ls and all(np.isfinite(ls))
+    assert runner.update_traces == 2
+    assert runner.trained_samples == runner.predictions
+
+
 def test_collect_shapes_and_logprob_consistency():
     from repro.models.policy import init_policy, log_prob, policy_apply
     env = make_env("Ant")

@@ -129,7 +129,7 @@ def _pallas_names(text):
 def test_kernel_compiles_for_v5e(one_chip, lower):
     compiled = lower(one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    # the eager A3C trainer runs ops.nstep_returns as this very program
+    # a kernel's instruction is named after its jitted ops wrapper
     names = _pallas_names(compiled.as_text())
     assert names and all(n.startswith(lower.__name__.lstrip("_"))
                          for n in names), names
@@ -193,3 +193,33 @@ def test_pallas_instruction_names_in_their_programs(one_chip,
                                                     lower, kernel):
     names = _pallas_names(lower(one_chip).compile().as_text())
     assert len(names) == 1 and names[0].startswith(kernel), names
+
+
+def _a3c_update(s, N=2 * N_ENVS):
+    """The async runner's compiled update at the Ant cell's batch."""
+    from repro.rl.a3c import AsyncRunner, Experience
+    runner = AsyncRunner(make_env("Ant"), [0], [100], num_envs=8,
+                         num_steps=T, use_fused_kernels=True)
+    f32 = jnp.float32
+    exp = Experience(obs=_sds((T, N, 60), f32, s),
+                     actions=_sds((T, N, 8), f32, s),
+                     rewards=_sds((T, N), f32, s), dones=_sds((T, N), f32, s),
+                     bootstrap=_sds((N,), f32, s),
+                     actor_version=_sds((), jnp.int32, s))
+    state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, s),
+                         (runner.params, runner.opt_state))
+    return runner._update.lower(*state, exp)
+
+
+def test_a3c_update_runs_nstep_returns_outside_the_gradient_on_hbm(
+        one_chip, compiled_kernels):
+    """``bench/metrics/nstep_roofline.py`` finds the kernel by this name
+    and bounds it by HBM bandwidth: the returns are computed outside the
+    gradient (under it the instruction is a ``jvp_...`` one) and read and
+    write HBM, not on-chip memory (``S(1)``)."""
+    text = _a3c_update(one_chip).compile().as_text()
+    names = _pallas_names(text)
+    assert len(names) == 1 and names[0].startswith("nstep_returns"), names
+    line = next(ln for ln in text.splitlines()
+                if re.match(rf"^\s*(?:ROOT )?%{re.escape(names[0])} = ", ln))
+    assert "S(1)" not in line, line
