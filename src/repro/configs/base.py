@@ -46,6 +46,24 @@ class ModelConfig:
     # independent of batch composition AND of prefill chunking whenever
     # chunk boundaries land on multiples of R.
     moe_route_block: int = 0
+    # DeepSeek-V3-style experts (``models/moe.py::routed_moe_apply``):
+    # sigmoid scores, a fixed choice-only bias, normalised top-k gates
+    # times ``routed_scale``, ``num_shared_experts`` always-on experts;
+    # this chip holds ``experts_held`` of the ``num_experts`` the router
+    # scores (0: all); the first ``first_dense_layers`` layers are dense
+    # with MLP width ``dense_d_ff`` (``d_ff`` is then the expert width)
+    num_shared_experts: int = 0
+    routed_scale: float = 1.0
+    experts_held: int = 0
+    first_dense_layers: int = 0
+    dense_d_ff: int = 0
+    router_bias_std: float = 0.0             # the seeded choice bias
+
+    # latent attention (MLA) -------------------------------------------------
+    kv_lora_rank: int = 0                    # 0: not latent attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # SSM / hybrid ---------------------------------------------------------
     # block pattern within one "super-block"; the stack is

@@ -8,8 +8,10 @@ Any other backend is an error: there is no silent interpret fallback.
 from __future__ import annotations
 
 import functools
+import importlib
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels.channel_pack import pack_channels as _pack
 from repro.kernels.env_megakernel import env_mega_step as _envmega
@@ -19,6 +21,11 @@ from repro.kernels.gae_scan import gae_scan as _gae
 from repro.kernels.gae_scan import nstep_scan as _nstep
 from repro.kernels.mlstm_scan import mlstm_chunkwise as _mlstm
 from repro.kernels.paged_decode import paged_decode_attention as _paged
+
+# the kernels' module (the package exports its custom-VJP ``gmm`` under
+# the module's name)
+_megablox = importlib.import_module(
+    "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
 def _interpret_default() -> bool:
@@ -116,3 +123,62 @@ def pack_channels(bufs, payloads, slot, *, interpret=None):
     one kernel launch; ring buffers donated)."""
     interp = _interpret_default() if interpret is None else interpret
     return _pack(bufs, payloads, slot, interpret=interp)
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    """(tm, tk, tn): 512-row tiles, the whole output width where it is
+    at most 2048 lanes (so a row block is read once), else 512/256/128."""
+    def fit(d, widest):
+        for t in (widest, 512, 256, 128):
+            if t <= d and d % t == 0 and t % 128 == 0:
+                return t
+        return d
+    return fit(m, 512), fit(k, 512), fit(n, n if n <= 2048 else 512)
+
+
+def _rows_held(x, group_sizes):
+    """Rows past ``sum(group_sizes)`` are never written by the kernel."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(rows < jnp.sum(group_sizes), x, 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm(lhs, rhs, group_sizes, dtypes, interpret):
+    return _gmm_fwd(lhs, rhs, group_sizes, dtypes, interpret)[0]
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, dtypes, interpret):
+    xb, wb = lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16)
+    out = _megablox.gmm(xb, wb, group_sizes, jnp.float32,
+                        _gmm_tiling(lhs.shape[0], lhs.shape[1],
+                                    rhs.shape[2]), interpret=interpret)
+    return _rows_held(out, group_sizes), (xb, wb, group_sizes)
+
+
+def _gmm_bwd(dtypes, interpret, res, dy):
+    xb, wb, group_sizes = res
+    m, k, n = xb.shape[0], xb.shape[1], wb.shape[2]
+    dyb = dy.astype(jnp.bfloat16)
+    dx = _megablox.gmm(dyb, wb, group_sizes, jnp.float32,
+                       _gmm_tiling(m, n, k), transpose_rhs=True,
+                       interpret=interpret)
+    dw = _megablox.tgmm(xb.T, dyb, group_sizes, jnp.float32,
+                        _gmm_tiling(m, k, n), interpret=interpret)
+    return (_rows_held(dx, group_sizes).astype(dtypes[0]),
+            dw.astype(dtypes[1]), None)
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def gmm(lhs, rhs, group_sizes, *, interpret=None):
+    """Grouped matrix product (Pallas megablox ``gmm``, weight gradient by
+    its ``tgmm``): rows ``[o_g, o_g + group_sizes[g])`` of ``lhs`` (M, K),
+    with ``o_g`` the sizes before ``g``, times ``rhs[g]`` (K, N).  Rows
+    past ``sum(group_sizes)`` come back zero.  Operands enter the MXU as
+    bf16 and accumulate in f32, as a float32 product does at the TPU's
+    default precision; the result is float32.  M must be a multiple of
+    128 (or under it)."""
+    interp = _interpret_default() if interpret is None else interpret
+    dtypes = (jnp.dtype(lhs.dtype), jnp.dtype(rhs.dtype))
+    return _gmm(lhs, rhs, group_sizes.astype(jnp.int32), dtypes, interp)

@@ -216,3 +216,18 @@ def mlstm_chunkwise_ref(q, k, v, log_i, log_f, chunk: int = 64):
             C, n, m)
         outs.append(h)
     return jnp.concatenate(outs, axis=2).astype(q.dtype)
+
+
+def gmm_ref(lhs, rhs, group_sizes):
+    """Grouped-matmul oracle: each row times the matrix of the group it
+    falls in (groups are consecutive row ranges of ``group_sizes``); rows
+    past ``sum(group_sizes)`` are zero.  float32 at HIGHEST."""
+    rows = jnp.arange(lhs.shape[0])
+    ends = jnp.cumsum(group_sizes)
+    group = jnp.searchsorted(ends, rows, side="right")
+    held = rows < ends[-1]
+    w = rhs[jnp.minimum(group, rhs.shape[0] - 1)]          # (M, K, N)
+    out = jnp.einsum("mk,mkn->mn", lhs.astype(jnp.float32),
+                     w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    return jnp.where(held[:, None], out, 0.0)

@@ -278,6 +278,24 @@ def make_drl_train_step(env, ppo_cfg=None, grad_sync_fn=None,
     return make_train_step(env, cfg, sync), cfg
 
 
+def make_lm_policy_train_step(model_cfg: ModelConfig, grpo_cfg=None,
+                              first_expert: int = 0):
+    """Jitted GRPO step for a token policy on the latent-attention MoE
+    stack (``rl/grpo.py::train_step``): ``(params, opt_state, batch) ->
+    (params, opt_state, metrics)``.  The parameters and Adam state are
+    donated (a step holds a second copy of neither); the MoE layers hold
+    experts ``[first_expert, first_expert + model_cfg.experts_held)``."""
+    from repro.rl import grpo
+    cfg = grpo_cfg if grpo_cfg is not None else grpo.GRPOConfig()
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def step(params, opt_state, batch):
+        return grpo.train_step(params, opt_state, batch, cfg, model_cfg,
+                               first_expert)
+
+    return step
+
+
 def make_experience_pipeline(layout, batch_mode: str = "stack",
                              batch_envs: Optional[int] = None,
                              overlap: bool = False):

@@ -1,4 +1,5 @@
-"""GQA attention with sliding-window, logit softcap, QKV-bias, KV caches.
+"""GQA attention with sliding-window, logit softcap, QKV-bias, KV caches;
+and latent attention (MLA) for a training pass (:func:`mla`).
 
 Two execution paths:
   * ``direct``  — materializes (…, Sq, Skv) scores; used for small sequences
@@ -29,7 +30,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, init_linear, linear, softcap
+from repro.models.layers import (apply_rope, init_linear, linear,
+                                 rms_norm, softcap)
 
 NEG_INF = -1e30
 
@@ -149,14 +151,20 @@ def _direct_attention(q, k, v, qpos, kpos, causal, window, cap, scale):
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, Sq, H, hd).astype(q.dtype)
+    return out.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def _chunked_attention(q, k, v, qpos, kpos, causal, window, cap, scale,
                        q_block: int = 512, kv_block: int = 1024):
-    """Flash-style blocked attention with online softmax (pure lax.scan)."""
+    """Flash-style blocked attention with online softmax (pure lax.scan).
+
+    The value width ``dv`` may differ from the query/key width ``hd``
+    (latent attention: 192-wide queries and keys, 128-wide values).  Each
+    query block is rematerialized under a gradient, so a backward pass
+    holds one block's scores at a time, not every block's."""
     B, Sq, H, hd = q.shape
     Skv, KH = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     G = H // KH
     qb = min(q_block, Sq)
     kb = min(kv_block, Skv)
@@ -173,10 +181,11 @@ def _chunked_attention(q, k, v, qpos, kpos, causal, window, cap, scale,
                     constant_values=-1)
     qp = qp.reshape(B, nq, qb, H, hd).transpose(1, 0, 2, 3, 4)
     kp = kp.reshape(B, nk, kb, KH, hd).transpose(1, 0, 2, 3, 4)
-    vp = vp.reshape(B, nk, kb, KH, hd).transpose(1, 0, 2, 3, 4)
+    vp = vp.reshape(B, nk, kb, KH, dv).transpose(1, 0, 2, 3, 4)
     qposp = jnp.broadcast_to(qposp, (B, nq * qb)).reshape(B, nq, qb).transpose(1, 0, 2)
     kposp = jnp.broadcast_to(kposp, (B, nk * kb)).reshape(B, nk, kb).transpose(1, 0, 2)
 
+    @jax.checkpoint
     def q_step(_, qc):
         qi, qpi = qc                                    # (B,qb,H,hd), (B,qb)
         qf = (qi * scale).astype(k.dtype).reshape(B, qb, KH, G, hd)
@@ -200,13 +209,13 @@ def _chunked_attention(q, k, v, qpos, kpos, causal, window, cap, scale,
 
         init = (jnp.full((B, KH, G, qb), NEG_INF, jnp.float32),
                 jnp.zeros((B, KH, G, qb), jnp.float32),
-                jnp.zeros((B, KH, G, qb, hd), jnp.float32))
+                jnp.zeros((B, KH, G, qb, dv), jnp.float32))
         (m, l, acc), _ = jax.lax.scan(kv_step, init, (kp, vp, kposp))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return None, out.transpose(0, 3, 1, 2, 4).reshape(B, qb, H, hd)
+        return None, out.transpose(0, 3, 1, 2, 4).reshape(B, qb, H, dv)
 
     _, outs = jax.lax.scan(q_step, None, (qp, qposp))
-    out = outs.transpose(1, 0, 2, 3, 4).reshape(B, nq * qb, H, hd)
+    out = outs.transpose(1, 0, 2, 3, 4).reshape(B, nq * qb, H, dv)
     return out[:, :Sq].astype(q.dtype)
 
 
@@ -295,3 +304,38 @@ def attention(params, x, *, num_heads: int, num_kv_heads: int, head_dim: int,
                                 causal, window, attn_cap, scale)
     out = linear(params["wo"], out.reshape(B, S, num_heads * head_dim))
     return out, new_cache
+
+
+# --------------------------------------------------------------------------
+def mla(params, x, positions, *, num_heads: int, qk_nope_dim: int,
+        qk_rope_dim: int, v_dim: int, rope_theta: float, norm_eps: float,
+        chunked_threshold: int = 4096):
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query LoRA,
+    for a training pass: full keys and values are built from the latent.
+
+    ``q = x W_q`` split per head into ``qk_nope_dim`` + ``qk_rope_dim``;
+    ``[c, k_r] = x W_kva``, ``c`` RMS-normed; ``[k_nope, v] = c W_kvb``
+    per head; one rotated ``k_r`` is shared by every head.  Causal scores
+    ``(q_nope k_nope + q_r k_r) / sqrt(qk_nope_dim + qk_rope_dim)``; the
+    ``v_dim``-wide head outputs go through ``W_o``.  Rope pairs use
+    :func:`apply_rope`'s half-split layout.  x: (B, S, D); positions:
+    (S,) or (B, S)."""
+    B, S, _ = x.shape
+    H, dn, dr = num_heads, qk_nope_dim, qk_rope_dim
+    if positions.ndim == 1:
+        positions = jnp.broadcast_to(positions[None], (B, S))
+    q = linear(params["wq"], x).reshape(B, S, H, dn + dr)
+    kv_a = linear(params["wkv_a"], x)
+    c = rms_norm(params["kv_norm"], kv_a[..., :-dr], norm_eps)
+    kv = linear(params["wkv_b"], c).reshape(B, S, H, dn + v_dim)
+    q = jnp.concatenate(
+        [q[..., :dn], apply_rope(q[..., dn:], positions, rope_theta)], -1)
+    k_r = apply_rope(kv_a[..., None, -dr:], positions, rope_theta)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_r, (B, S, H, dr))], -1)
+    v = kv[..., dn:]
+    scale = (dn + dr) ** -0.5
+    attend = _chunked_attention if S > chunked_threshold \
+        else _direct_attention
+    out = attend(q, k, v, positions, positions, True, None, None, scale)
+    return linear(params["wo"], out.reshape(B, S, H * v_dim))

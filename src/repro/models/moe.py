@@ -1,4 +1,9 @@
-"""Mixture-of-Experts layer: top-k router + capacity-bucketed dispatch.
+"""Mixture-of-Experts layers.
+
+``moe_apply``: softmax top-k router + capacity-bucketed dispatch, every
+expert held (mixtral, granite, serving).  ``routed_moe_apply`` (end of the
+file): DeepSeek-V3 routing over the published experts, of which this chip
+holds a share, dropless through the grouped expert kernel.
 
 Dispatch is *grouped*: tokens are routed within their (sharded) batch row.
 The scatter/gather is expressed BATCHED (leading B dim everywhere, no vmap)
@@ -18,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.models.layers import mlp
 from repro.utils import lecun_init
 
 # ---------------------------------------------------------------------------
@@ -157,3 +163,75 @@ def moe_apply(params, x, *, num_experts: int, top_k: int,
     frac_probs = jnp.mean(probs, axis=(0, 1))
     aux = aux_coef * E * jnp.sum(frac_tokens * frac_probs)
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# Sigmoid-routed experts with a choice-only bias and shared experts
+# (DeepSeek-V3 ``noaux_tc`` routing), of which this chip holds a share.
+#
+# The router scores all ``num_experts`` published experts; the layer holds
+# experts ``[first_expert, first_expert + held)`` and computes only their
+# part of the result.  Held assignments are sorted by expert and run through
+# the grouped expert kernel (``kernels/ops.py::gmm``): no capacity, nothing
+# dropped.  What the absent experts add is another chip's part.
+
+
+def _route(params, x, top_k: int, routed_scale: float):
+    """x: (T, D) -> (experts (T, k) int32, gates (T, k) f32).  Sigmoid
+    scores; the top-k by score plus the bias (held fixed: no gradient);
+    gates are the chosen scores normalised to sum 1, times the scale."""
+    s = jax.nn.sigmoid((x @ params["router"].astype(x.dtype))
+                       .astype(jnp.float32))
+    _, experts = jax.lax.top_k(s + jax.lax.stop_gradient(params["bias"]),
+                               top_k)
+    gates = jnp.take_along_axis(s, experts, axis=-1)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True) * routed_scale
+    return experts, gates
+
+
+def _held_experts(experts_p, x, local, gates, sizes):
+    """The held experts' part of the result, dropless.  x: (T, D); local:
+    (T, k) the held expert of each assignment, or ``held`` where it is
+    held elsewhere or the token is padding; gates: (T, k); sizes: (held,)
+    assignments per held expert.  Returns (out (T, D), dropped)."""
+    from repro.kernels import ops
+    T, D = x.shape
+    k = local.shape[1]
+    order = jnp.argsort(local.reshape(-1), stable=True)   # held first
+    rows = -(-T * k // 128) * 128                          # kernel's M
+    xs = x[jnp.pad(order // k, (0, rows - T * k))]
+    h = ops.gmm(xs, experts_p["wi"], sizes)
+    g = ops.gmm(xs, experts_p["wg"], sizes)
+    y = ops.gmm((jax.nn.silu(g) * h).astype(x.dtype), experts_p["wo"], sizes)
+    # back to (token, choice) order; rows past the held ones read zero
+    y = y[jnp.argsort(order)].reshape(T, k, D)
+    out = jnp.einsum("tkd,tk->td", y, gates.astype(y.dtype))
+    return out.astype(x.dtype), jnp.int32(0)
+
+
+def routed_moe_apply(params, x, valid, *, top_k: int, routed_scale: float,
+                     first_expert: int = 0):
+    """x: (B, S, D); valid: (B, S) bool, the real tokens (padding is
+    routed nowhere).  Returns (out (B, S, D), counters): ``assignments``
+    (held,) per held expert, ``offchip`` real assignments to experts held
+    elsewhere, ``dropped`` assignments (0: the layer has no capacity)."""
+    B, S, D = x.shape
+    xt = x.reshape(B * S, D)
+    with jax.named_scope("moe/route"):
+        experts, gates = _route(params, xt, top_k, routed_scale)
+        E = params["experts"]["wi"].shape[0]
+        real = valid.reshape(B * S, 1)
+        held = real & (experts >= first_expert) \
+            & (experts < first_expert + E)
+        local = jnp.where(held, experts - first_expert, E)
+        sizes = jnp.sum(jax.nn.one_hot(local, E, dtype=jnp.int32),
+                        axis=(0, 1))
+    with jax.named_scope("moe/experts"):
+        routed, dropped = _held_experts(params["experts"], xt, local,
+                                        jnp.where(held, gates, 0.0), sizes)
+    with jax.named_scope("moe/shared"):
+        shared = mlp(params["shared"], xt)
+    counters = {"assignments": sizes,
+                "offchip": jnp.sum(real & ~held).astype(jnp.int32),
+                "dropped": dropped}
+    return (routed + shared).reshape(B, S, D), counters

@@ -594,3 +594,136 @@ def prefill_chunk(params, cfg: ModelConfig, tokens, positions, caches, slot,
     caches = jax.tree.map(back, caches, new_view, is_leaf=_is_cache_node)
     h = rms_norm(params["final_norm"], h[:, -1:], cfg.norm_eps)
     return _logits(params, cfg, h)[:, 0], caches
+
+
+# ======================================================================
+# latent-attention MoE stack (DeepSeek-V3 layout), for policy training
+# ======================================================================
+# ``first_dense_layers`` dense layers, then MoE layers, each scanned over
+# a stacked params pytree; every layer is pre-norm MLA plus an MLP (dense)
+# or held routed experts and shared experts (``models/moe.py``).  The
+# embedding and the head cover the vocabulary slice held here.
+
+
+def latent_moe_shapes(cfg: ModelConfig) -> Dict[str, Any]:
+    """The params pytree of :func:`init_latent_moe` as shapes."""
+    D, H = cfg.d_model, cfg.num_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    r, V, F = cfg.kv_lora_rank, cfg.vocab_size, cfg.d_ff
+    E, Eh = cfg.num_experts, cfg.experts_held or cfg.num_experts
+    Fs = cfg.num_shared_experts * F
+
+    def layer(n, ffn):
+        one = {"ln1": {"scale": (D,)}, "ln2": {"scale": (D,)},
+               "mla": {"wq": {"w": (D, H * (dn + dr))},
+                       "wkv_a": {"w": (D, r + dr)},
+                       "kv_norm": {"scale": (r,)},
+                       "wkv_b": {"w": (r, H * (dn + dv))},
+                       "wo": {"w": (H * dv, D)}}, **ffn}
+        return jax.tree.map(lambda s: (n,) + s, one,
+                            is_leaf=lambda s: isinstance(s, tuple))
+
+    Fd = cfg.dense_d_ff
+    return {
+        "embed": {"table": (V, D)},
+        "dense": layer(cfg.first_dense_layers,
+                       {"mlp": {"wi": (D, Fd), "wg": (D, Fd),
+                                "wo": (Fd, D)}}),
+        "layers": layer(cfg.num_layers - cfg.first_dense_layers,
+                        {"moe": {"router": (D, E), "bias": (E,),
+                                 "experts": {"wi": (Eh, D, F),
+                                             "wg": (Eh, D, F),
+                                             "wo": (Eh, F, D)},
+                                 "shared": {"wi": (D, Fs), "wg": (D, Fs),
+                                            "wo": (Fs, D)}}}),
+        "final_norm": {"scale": (D,)},
+        "unembed": {"w": (D, V)},
+    }
+
+
+def init_latent_moe(key, cfg: ModelConfig) -> Dict[str, Any]:
+    """Weights drawn leaf by leaf in the pytree's flattening order: leaf i
+    is ``normal(fold_in(key, i), shape) * scale``, the scale being the
+    fan-in (second-to-last dim) to the power -1/2 for a matrix, 1 for the
+    embedding, ``cfg.router_bias_std`` for the choice bias; norm scales
+    are ones."""
+    paths, tree = jax.tree_util.tree_flatten_with_path(
+        latent_moe_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+
+    def draw(i, path, shape):
+        name = path[-1].key
+        if name == "scale":
+            return jnp.ones(shape, jnp.float32)
+        scale = {"table": 1.0, "bias": cfg.router_bias_std}.get(
+            name, shape[-2] ** -0.5 if len(shape) >= 2 else 1.0)
+        return jax.random.normal(jax.random.fold_in(key, i), shape,
+                                 jnp.float32) * scale
+
+    return jax.tree_util.tree_unflatten(
+        tree, [draw(i, p, s) for i, (p, s) in enumerate(paths)])
+
+
+def _latent_layer(lp, x, cfg: ModelConfig, positions, valid, first_expert):
+    from repro.models.attention import mla
+    from repro.models.moe import routed_moe_apply
+    h = rms_norm(lp["ln1"], x, cfg.norm_eps)
+    with jax.named_scope("mla"):
+        x = x + mla(lp["mla"], h, positions, num_heads=cfg.num_heads,
+                    qk_nope_dim=cfg.qk_nope_head_dim,
+                    qk_rope_dim=cfg.qk_rope_head_dim,
+                    v_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta,
+                    norm_eps=cfg.norm_eps)
+    h = rms_norm(lp["ln2"], x, cfg.norm_eps)
+    if "moe" in lp:
+        m, counters = routed_moe_apply(
+            lp["moe"], h, valid, top_k=cfg.experts_per_token,
+            routed_scale=cfg.routed_scale, first_expert=first_expert)
+        return x + m, counters
+    return x + mlp(lp["mlp"], h, cfg.act), None
+
+
+def latent_moe_hidden(params, cfg: ModelConfig, tokens, valid,
+                      first_expert: int = 0):
+    """tokens, valid: (B, S) -> (final-normed hidden (B, S, D), counters
+    of the MoE layers, each stacked (layers, ...)).  Each layer is
+    rematerialized under a gradient."""
+    x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
+    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+
+    def body(h, lp):
+        return _latent_layer(lp, h, cfg, positions, valid, first_expert)
+
+    body = jax.checkpoint(body)
+    x, _ = jax.lax.scan(body, x, params["dense"])
+    x, counters = jax.lax.scan(body, x, params["layers"])
+    return rms_norm(params["final_norm"], x, cfg.norm_eps), counters
+
+
+def token_logprobs(params, cfg: ModelConfig, tokens, valid,
+                   first_expert: int = 0, chunk: int = 512):
+    """Log-probability of each next token over the vocabulary slice:
+    (B, S - 1) for ``tokens[:, 1:]``, plus the MoE counters.  The head is
+    applied over position chunks (as ``_chunked_xent``), each
+    rematerialized, so (B, S, V) logits never exist at once."""
+    h, counters = latent_moe_hidden(params, cfg, tokens, valid,
+                                    first_expert)
+    h, labels = h[:, :-1], tokens[:, 1:]
+    B, S, D = h.shape
+    L = min(chunk, S)
+    nc = -(-S // L)
+    pad = nc * L - S
+    hp = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+    hp = hp.reshape(B, nc, L, D).transpose(1, 0, 2, 3)
+    lp = jnp.pad(labels, ((0, 0), (0, pad))).reshape(B, nc, L)
+    lp = lp.transpose(1, 0, 2)
+
+    @jax.checkpoint
+    def step(_, xs):
+        hc, lc = xs
+        with jax.named_scope("lm_head"):
+            logits = linear(params["unembed"], hc).astype(jnp.float32)
+            picked = jnp.take_along_axis(logits, lc[..., None], -1)[..., 0]
+            return None, picked - jax.nn.logsumexp(logits, axis=-1)
+
+    _, out = jax.lax.scan(step, None, (hp, lp))
+    return out.transpose(1, 0, 2).reshape(B, nc * L)[:, :S], counters

@@ -38,6 +38,14 @@ class PPOConfig(NamedTuple):
     use_fused_kernels: bool = False
 
 
+def clipped_surrogate(ratio, advs, clip_eps):
+    """PPO's clipped policy-gradient objective, negated (a loss), per
+    sample: ``-min(r A, clip(r, 1 - eps, 1 + eps) A)``.  Shared by the
+    policy-MLP loss below and the LLM-policy step (``rl/grpo.py``)."""
+    return -jnp.minimum(ratio * advs,
+                        jnp.clip(ratio, 1 - clip_eps, 1 + clip_eps) * advs)
+
+
 def ppo_loss(params, batch, clip_eps, vf_coef, ent_coef,
              policy_fn=policy_apply, normalize_adv: bool = True):
     obs, actions, old_lp, advs, returns = batch
@@ -46,8 +54,7 @@ def ppo_loss(params, batch, clip_eps, vf_coef, ent_coef,
     ratio = jnp.exp(lp - old_lp)
     advs_n = (advs - advs.mean()) / (advs.std() + 1e-8) \
         if normalize_adv else advs
-    pg = -jnp.minimum(ratio * advs_n,
-                      jnp.clip(ratio, 1 - clip_eps, 1 + clip_eps) * advs_n)
+    pg = clipped_surrogate(ratio, advs_n, clip_eps)
     vf = 0.5 * jnp.square(value - returns)
     ent = entropy(log_std)
     loss = pg.mean() + vf_coef * vf.mean() - ent_coef * ent.mean()

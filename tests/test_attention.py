@@ -36,6 +36,28 @@ def test_chunked_with_softcap_and_ragged_blocks():
     np.testing.assert_allclose(o1, o2, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("dv", [8, 24])
+def test_chunked_matches_direct_value_width(dv):
+    """Values narrower or wider than queries and keys (latent attention:
+    192-wide queries and keys, 128-wide values); causal, ragged blocks."""
+    B, S, H, KH, hd = 2, 40, 4, 4, 16
+    q = jax.random.normal(jax.random.key(7), (B, S, H, hd))
+    k = jax.random.normal(jax.random.key(8), (B, S, KH, hd))
+    v = jax.random.normal(jax.random.key(9), (B, S, KH, dv))
+    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    o1 = _direct_attention(q, k, v, pos, pos, True, None, None, hd**-0.5)
+    o2 = _chunked_attention(q, k, v, pos, pos, True, None, None, hd**-0.5,
+                            q_block=16, kv_block=16)
+    assert o1.shape == o2.shape == (B, S, H, dv)
+    np.testing.assert_allclose(o1, o2, rtol=2e-5, atol=2e-5)
+    g1 = jax.grad(lambda v: jnp.sum(_direct_attention(
+        q, k, v, pos, pos, True, None, None, hd**-0.5) ** 2))(v)
+    g2 = jax.grad(lambda v: jnp.sum(_chunked_attention(
+        q, k, v, pos, pos, True, None, None, hd**-0.5, q_block=16,
+        kv_block=16) ** 2))(v)
+    np.testing.assert_allclose(g1, g2, rtol=1e-4, atol=1e-4)
+
+
 def test_ring_cache_decode_matches_full_cache():
     """Sliding-window decode via ring buffer == full cache + window mask."""
     key = jax.random.key(6)

@@ -265,3 +265,29 @@ def test_paged_attention_kernel_gqa_softcap():
                               interpret=True)
     want = ref.paged_attention_ref(q, kp, vp, sp, table, pos, softcap=20.0)
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("sizes", [[30, 0, 77, 50], [0, 0, 0, 0],
+                                   [128, 0, 0, 128]])
+def test_gmm_kernel(sizes):
+    """The grouped expert kernel against its oracle on bf16-rounded
+    operands (the kernel's MXU operands), forward and both gradients;
+    rows past the groups read zero and take no gradient."""
+    M, K, N = 256, 64, 32
+    ks = jax.random.split(KEY, 3)
+    bf = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    x = bf(jax.random.normal(ks[0], (M, K)))
+    w = bf(jax.random.normal(ks[1], (4, K, N)))
+    dy = bf(jax.random.normal(ks[2], (M, N)))
+    gs = jnp.asarray(sizes, jnp.int32)
+    out, vjp = jax.vjp(lambda x, w: ops.gmm(x, w, gs), x, w)
+    want, vjp_ref = jax.vjp(lambda x, w: ref.gmm_ref(x, w, gs), x, w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+    assert not np.any(np.asarray(out)[sum(sizes):])
+    for got, exp in zip(vjp(dy), vjp_ref(dy)):
+        scale = float(jnp.abs(exp).max()) or 1.0
+        # the cotangent enters the MXU as bf16 too
+        np.testing.assert_allclose(np.asarray(got) / scale,
+                                   np.asarray(exp) / scale, atol=1e-2)
+    assert not np.any(np.asarray(vjp(dy)[0])[sum(sizes):])

@@ -117,6 +117,15 @@ def _paged_attention(s):
         _sds((B, M), i32, s), _sds((B,), i32, s), interpret=False)
 
 
+def _gmm(s):
+    # one Moonlight MoE layer's held experts (8 of width 1408 at d_model
+    # 2048) over a 1-row minibatch's assignment buffer (8192 tokens x 6)
+    f32 = jnp.float32
+    return jax.jit(lambda x, w, n: ops.gmm(x, w, n, interpret=False)).lower(
+        _sds((8192 * 6, 2048), f32, s), _sds((8, 2048, 1408), f32, s),
+        _sds((8,), jnp.int32, s))
+
+
 def _pallas_names(text):
     """Instruction names of the compiled Pallas kernels in ``text``."""
     return re.findall(r"^\s*(?:ROOT )?%(\S+) = .*tpu_custom_call", text,
@@ -124,7 +133,7 @@ def _pallas_names(text):
 
 
 @pytest.mark.parametrize("lower", [_env_mega_step, _pack_channels, _gae_norm,
-                                   _nstep_returns, _paged_attention],
+                                   _nstep_returns, _paged_attention, _gmm],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(one_chip, lower):
     compiled = lower(one_chip).compile()
@@ -223,3 +232,33 @@ def test_a3c_update_runs_nstep_returns_outside_the_gradient_on_hbm(
     line = next(ln for ln in text.splitlines()
                 if re.match(rf"^\s*(?:ROOT )?%{re.escape(names[0])} = ", ln))
     assert "S(1)" not in line, line
+
+
+def _lm_policy_step(s, G=2, S=256):
+    """Moonlight's GRPO step at its published widths, the dense layer and
+    one MoE layer, on short rows."""
+    from repro.configs.moonlight_16b_a3b import EP8
+    from repro.launch.steps import make_lm_policy_train_step
+    from repro.models.transformer import latent_moe_shapes
+    from repro.optim import adam_init
+    from repro.rl.grpo import GRPOConfig, TokenBatch
+    cfg = EP8.replace(num_layers=2)
+    params = jax.tree.map(lambda x: _sds(x, jnp.float32, s),
+                          latent_moe_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    opt = jax.tree.map(lambda x: _sds(x.shape, x.dtype, s),
+                       jax.eval_shape(adam_init, params))
+    batch = TokenBatch(_sds((G, S), jnp.int32, s), _sds((G, S), jnp.bool_, s),
+                       _sds((G, S - 1), jnp.float32, s),
+                       _sds((G,), jnp.float32, s))
+    step = make_lm_policy_train_step(cfg, GRPOConfig(num_minibatches=1))
+    return step.lower(params, opt, batch)
+
+
+def test_lm_policy_step_runs_the_grouped_kernels_by_name(one_chip,
+                                                         compiled_kernels):
+    """``bench/metrics/expert_gmm_roofline.py`` finds the grouped expert
+    kernel's calls by these names: ``gmm.N`` (forward and input gradient)
+    and ``tgmm.N`` (weight gradient), after megablox's jitted wrappers."""
+    names = _pallas_names(_lm_policy_step(one_chip).compile().as_text())
+    assert {n.split(".")[0] for n in names} == {"gmm", "tgmm"}, names
