@@ -255,7 +255,7 @@ def make_drl_train_step(env, ppo_cfg=None, grad_sync_fn=None,
                         fused: Optional[bool] = None, communicator=None):
     """Jitted sync-PPO iteration with the fused Pallas hot path on by
     default: the gae_scan kernel (GAE + advantage normalization in one
-    VMEM pass) and single-gather minibatch shuffling.  An explicit
+    VMEM pass).  An explicit
     ``ppo_cfg`` keeps its own ``use_fused_kernels`` unless ``fused``
     explicitly overrides it.  Gradient sync comes from ``communicator``
     (a ``repro.comm.Communicator``) when given, else ``grad_sync_fn``."""

@@ -11,6 +11,7 @@ single instance.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -32,9 +33,9 @@ class PPOConfig(NamedTuple):
     ent_coef: float = 0.01
     lr: float = 3e-4
     max_grad_norm: float = 1.0
-    # fused hot path: Pallas GAE+normalization kernel and single-gather
-    # minibatch shuffling (advantages arrive batch-normalized, so the loss
-    # skips its per-minibatch renormalization)
+    # fused hot path: the Pallas GAE+normalization kernel (advantages
+    # arrive batch-normalized, so the loss skips its per-minibatch
+    # renormalization)
     use_fused_kernels: bool = False
 
 
@@ -61,6 +62,42 @@ def ppo_loss(params, batch, clip_eps, vf_coef, ent_coef,
     return loss, (pg.mean(), vf.mean(), ent.mean())
 
 
+def pack_rows(fields):
+    """Pack arrays that share their leading (row) axis into one table.
+
+    Each field of the first field's dtype is flattened to ``(rows, width)``
+    and the fields are concatenated along the columns, at their natural
+    width.  A row gather of the table moves every field of a sample at
+    once: on the TPU a row gather costs per row, hardly per byte, so one
+    table replaces one gather per field.  A field of another dtype stays a
+    table of its own.
+
+    Returns ``(tables, unpack)``: ``unpack`` takes the tables gathered by
+    one index array (or any slice of them that keeps the column axis
+    last), in order, and gives the fields back in their order and
+    trailing shapes.
+    """
+    rows, dtype = fields[0].shape[0], fields[0].dtype
+    cols = [f.reshape(rows, -1) for f in fields if f.dtype == dtype]
+    tables = [jnp.concatenate(cols, axis=1)]
+    tables += [f for f in fields if f.dtype != dtype]
+
+    def unpack(gathered):
+        table, *own = gathered
+        lead, at, own = table.shape[:-1], 0, iter(own)
+        out = []
+        for f in fields:
+            if f.dtype != dtype:
+                out.append(next(own))
+                continue
+            width = math.prod(f.shape[1:])
+            out.append(table[..., at:at + width].reshape(lead + f.shape[1:]))
+            at += width
+        return tuple(out)
+
+    return tables, unpack
+
+
 def train_iteration(params, opt_state: AdamState, env, env_state, obs, key,
                     cfg: PPOConfig, grad_sync_fn: Optional[Callable] = None,
                     policy_fn=policy_apply):
@@ -84,9 +121,11 @@ def train_iteration(params, opt_state: AdamState, env, env_state, obs, key,
                                 last_value, cfg.gamma, cfg.lam)
 
     T, N = traj.rewards.shape
-    flat = jax.tree.map(lambda x: x.reshape((T * N,) + x.shape[2:]),
-                        (traj.obs, traj.actions, traj.log_probs, advs,
-                         returns))
+    with jax.named_scope("ppo/shuffle"):
+        tables, unpack = pack_rows(
+            [x.reshape((T * N,) + x.shape[2:])
+             for x in (traj.obs, traj.actions, traj.log_probs, advs,
+                       returns)])
     mb_size = (T * N) // cfg.num_minibatches
 
     def epoch(carry, _):
@@ -94,19 +133,16 @@ def train_iteration(params, opt_state: AdamState, env, env_state, obs, key,
         with jax.named_scope("ppo/shuffle"):
             key, pkey = jax.random.split(key)
             perm = jax.random.permutation(pkey, T * N)
-            if cfg.use_fused_kernels:
-                # single gather straight into minibatch layout — no
-                # shuffle-then-reshape copy chain through XLA
-                idx = perm.reshape((cfg.num_minibatches, mb_size))
-                mb = jax.tree.map(lambda x: jnp.take(x, idx, axis=0), flat)
-            else:
-                shuf = jax.tree.map(lambda x: x[perm], flat)
-                mb = jax.tree.map(
-                    lambda x: x.reshape((cfg.num_minibatches, mb_size)
-                                        + x.shape[1:]), shuf)
+            # one row gather per table, straight into minibatch layout; the
+            # fields are sliced out in the minibatch body, where the slices
+            # fuse into their uses, rather than each field of the whole
+            # epoch being copied out of the table (PERF.md section 5)
+            idx = perm.reshape((cfg.num_minibatches, mb_size))
+            mb = [t[idx] for t in tables]
 
         def minibatch(carry, batch):
             params, opt_state = carry
+            batch = unpack(batch)
             with jax.named_scope("ppo/loss_grad"):
                 (loss, aux), grads = jax.value_and_grad(
                     ppo_loss, has_aux=True)(

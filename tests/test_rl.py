@@ -1,3 +1,5 @@
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -208,3 +210,133 @@ def test_staleness_counter():
                      rewards=jnp.zeros((1, 1)), dones=jnp.zeros((1, 1)),
                      bootstrap=jnp.zeros((1,)), actor_version=jnp.int32(3))
     assert int(staleness(jnp.int32(7), exp)) == 4
+
+
+# ------------------------------------------- packed minibatch shuffling --
+class _SplitObs:
+    """An env whose observations carry an extra trailing dim: each
+    ``(N, D)`` observation of ``env`` is served as ``(N, rows, D / rows)``."""
+
+    def __init__(self, env, rows: int):
+        self.env, self.rows, self.spec = env, rows, env.spec
+
+    def reset(self, key, num_envs: int):
+        state, obs = self.env.reset(key, num_envs)
+        return state, obs.reshape(num_envs, self.rows, -1)
+
+    def step(self, state, action):
+        state, obs, reward, done = self.env.step(state, action)
+        return state, obs.reshape(obs.shape[0], self.rows, -1), reward, done
+
+
+def _flat_policy(params, obs):
+    from repro.models.policy import policy_apply
+    return policy_apply(params, obs.reshape(obs.shape[:-2] + (-1,)))
+
+
+def _packing_env(case):
+    """(env, policy_fn) for each obs case: table widths 30 and 234, neither
+    a multiple of 128, and an obs with an extra trailing dim."""
+    from repro.models.policy import policy_apply
+    if case == "split_obs":
+        return _SplitObs(make_env("BallBalance"), 4), _flat_policy
+    return make_env({"flat_24": "BallBalance",
+                     "flat_211": "ShadowHand"}[case]), policy_apply
+
+
+_PACK_CASES = ["flat_24", "split_obs", "flat_211"]
+
+
+def _per_array_pack(fields):
+    """The per-array shuffle: every trajectory field is its own table and
+    is row-gathered on its own."""
+    return list(fields), tuple
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["gae", "gae_fused"])
+@pytest.mark.parametrize("case", _PACK_CASES)
+def test_packed_gather_yields_the_per_array_minibatches(case, fused):
+    from repro.models.policy import init_policy
+    from repro.rl.ppo import pack_rows
+    from repro.rl.rollout import gae_fused
+    T, N, M = 4, 16, 4
+    env, policy_fn = _packing_env(case)
+    params = init_policy(jax.random.key(0), env.spec.policy_dims)
+    est, obs = env.reset(jax.random.PRNGKey(1), N)
+    traj, _, _, last_v, _ = collect(params, env, est, obs,
+                                    jax.random.PRNGKey(2), T, policy_fn)
+    advs, rets = (gae_fused if fused else gae)(
+        traj.rewards, traj.values, traj.dones, last_v, 0.99, 0.95)
+    fields = [x.reshape((T * N,) + x.shape[2:])
+              for x in (traj.obs, traj.actions, traj.log_probs, advs, rets)]
+    idx = jax.random.permutation(jax.random.PRNGKey(3), T * N) \
+        .reshape(M, T * N // M)
+
+    tables, unpack = pack_rows(fields)
+    width = sum(math.prod(x.shape[1:]) for x in fields)
+    assert [t.shape for t in tables] == [(T * N, width)]
+    got = unpack([t[idx] for t in tables])
+    want = [jnp.take(x, idx, axis=0) for x in fields]
+    for g, w in zip(got, want, strict=True):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_pack_rows_gives_a_field_of_another_dtype_its_own_table():
+    from repro.rl.ppo import pack_rows
+    R = 24
+    ks = jax.random.split(jax.random.key(0), 3)
+    fields = (jax.random.normal(ks[0], (R, 3, 5)),
+              jax.random.randint(ks[1], (R, 2), 0, 9),
+              jax.random.normal(ks[2], (R,)))
+    tables, unpack = pack_rows(fields)
+    assert [t.shape for t in tables] == [(R, 16), (R, 2)]
+    idx = jax.random.permutation(jax.random.PRNGKey(1), R).reshape(2, R // 2)
+    got = unpack([t[idx] for t in tables])
+    for g, f in zip(got, fields, strict=True):
+        assert g.dtype == f.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(f[idx]))
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["gae", "gae_fused"])
+@pytest.mark.parametrize("case", _PACK_CASES)
+def test_packed_ppo_step_is_bit_identical_to_per_array_gathers(
+        case, fused, monkeypatch):
+    from repro.rl import ppo
+    env, policy_fn = _packing_env(case)
+    cfg = PPOConfig(num_steps=4, num_epochs=2, num_minibatches=4, lr=1e-3,
+                    use_fused_kernels=fused)
+
+    def first_steps(n=3):
+        params, opt, est, obs = init_train(jax.random.key(0), env,
+                                           env.spec.policy_dims, 16)
+        step = make_train_step(env, cfg, policy_fn=policy_fn)
+        state, out = (params, opt, est, obs, jax.random.PRNGKey(6)), []
+        for _ in range(n):
+            *state, metrics = step(*state)
+            out.append((state[0], state[1], metrics))
+        return out
+
+    packed = first_steps()
+    monkeypatch.setattr(ppo, "pack_rows", _per_array_pack)
+    per_array = first_steps()
+    for g, w in zip(jax.tree.leaves(packed), jax.tree.leaves(per_array),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["gae", "gae_fused"])
+def test_lowered_ppo_step_gathers_the_samples_once_per_epoch(fused):
+    """The epoch scan row-gathers one packed table: exactly one gather in
+    the program reads an operand of ``T * N`` rows."""
+    import re
+    T, N = 4, 8
+    env = make_env("BallBalance")
+    step = make_train_step(env, PPOConfig(num_steps=T, num_epochs=2,
+                                          num_minibatches=2,
+                                          use_fused_kernels=fused))
+    p, o, es, obs = init_train(jax.random.key(0), env,
+                               env.spec.policy_dims, N)
+    text = step.lower(p, o, es, obs, jax.random.PRNGKey(1)).as_text()
+    rows = re.findall(r'"stablehlo\.gather".*?: \(tensor<(\d+)x', text)
+    assert rows.count(str(T * N)) == 1, rows
