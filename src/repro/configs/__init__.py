@@ -7,8 +7,7 @@ from __future__ import annotations
 
 import importlib
 
-from repro.configs.base import (INPUT_SHAPES, InputShape, ModelConfig,
-                                TrainConfig)  # noqa: F401
+from repro.configs.base import InputShape, ModelConfig  # noqa: F401
 
 _ARCH_MODULES = {
     "gemma2-27b": "gemma2_27b",
@@ -41,7 +40,7 @@ def get_reduced(arch: str) -> ModelConfig:
 
 
 def shape_skips(arch: str) -> dict:
-    """Which input shapes are skipped for this arch, and why (DESIGN.md §5).
+    """Which input shapes are skipped for this arch, and why.
 
     ``long_500k`` notes: archs without native sub-quadratic attention run it
     only under the sliding-window serving variant (window_override)."""
@@ -52,12 +51,3 @@ def shape_skips(arch: str) -> dict:
         skips["long_500k"] = "encoder-only: no autoregressive decode step"
     return skips
 
-
-def long_context_window(arch: str):
-    """window_override used for long_500k (None = native sub-quadratic)."""
-    cfg = get_config(arch)
-    if cfg.family in ("ssm", "hybrid"):
-        return None                      # recurrent state: O(1) per token
-    if cfg.sliding_window and not cfg.local_global:
-        return None                      # native SWA (mixtral)
-    return 4096                          # sliding-window serving variant

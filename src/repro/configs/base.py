@@ -1,8 +1,8 @@
 """Architecture configuration schema.
 
-Every assigned architecture gets a ``ModelConfig`` (full size, used only by
-the dry-run via ShapeDtypeStruct) plus a ``reduced()`` variant (<=2 layers,
-d_model<=512, <=4 experts) that the CPU smoke tests instantiate for real.
+Every assigned architecture gets a ``ModelConfig`` at its published size
+plus a ``reduced()`` variant (<=2 layers, d_model<=512, <=4 experts) that the
+CPU smoke tests instantiate for real.
 """
 from __future__ import annotations
 
@@ -84,7 +84,7 @@ class ModelConfig:
     act: str = "silu"
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
-    dtype: str = "float32"                   # compute dtype for dry-runs
+    dtype: str = "float32"                   # compute dtype
 
     # ------------------------------------------------------------------
     @property
@@ -136,32 +136,9 @@ class ModelConfig:
 
 @dataclass(frozen=True)
 class InputShape:
-    """One of the four assigned workload shapes."""
+    """A workload shape: sequence length, global batch and step mode."""
     name: str
     seq_len: int
     global_batch: int
     mode: str        # "train" | "prefill" | "decode"
 
-
-INPUT_SHAPES = {
-    "train_4k":    InputShape("train_4k",    4_096,   256, "train"),
-    "prefill_32k": InputShape("prefill_32k", 32_768,  32,  "prefill"),
-    "decode_32k":  InputShape("decode_32k",  32_768,  128, "decode"),
-    "long_500k":   InputShape("long_500k",   524_288, 1,   "decode"),
-}
-
-
-@dataclass(frozen=True)
-class TrainConfig:
-    learning_rate: float = 3e-4
-    weight_decay: float = 0.0
-    beta1: float = 0.9
-    beta2: float = 0.95
-    grad_clip: float = 1.0
-    warmup_steps: int = 10
-    total_steps: int = 100
-    # GMI-DRL runtime knobs
-    lgr_strategy: str = "auto"       # auto | mpr | mrr | har
-    gmi_layout: str = "tcg"          # tcg | tdg
-    remat: bool = True
-    microbatches: int = 1            # gradient-accumulation splits

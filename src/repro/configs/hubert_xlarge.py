@@ -1,6 +1,6 @@
 """hubert-xlarge [audio] — 48L d_model=1280 16H (kv=16) d_ff=5120 vocab=504
 (k-means target codebook); encoder-only, same trunk as wav2vec2.
-The conv/mel frontend is a STUB: input_specs() feeds precomputed frame
+The conv/mel frontend is a STUB: ``data.make_batch`` feeds precomputed frame
 embeddings (B, T, 512). [arXiv:2106.07447]
 """
 from repro.configs.base import ModelConfig

@@ -1,6 +1,6 @@
 """pixtral-12b [vlm] — 40L d_model=5120 32H (GQA kv=8) d_ff=14336
 vocab=131072; pixtral-ViT vision encoder + mistral-nemo decoder.  The ViT is
-a STUB: input_specs() feeds projected patch embeddings (B, P, 1024) that are
+a STUB: ``data.make_batch`` feeds projected patch embeddings (B, P, 1024)
 interleaved ahead of the text tokens. [hf:mistralai/Pixtral-12B-2409]
 """
 from repro.configs.base import ModelConfig

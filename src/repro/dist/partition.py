@@ -1,4 +1,4 @@
-"""Partition-spec rules for the production mesh (DESIGN.md §2).
+"""Partition-spec rules for a ``(data, model)`` or ``(pod, data, model)`` mesh.
 
 Specs are derived per-leaf from the leaf's tree path and shape, never from a
 per-architecture table, so every config in ``repro.configs.ARCHS`` shards

@@ -1,6 +1,4 @@
-# Launch layer: mesh.py (production mesh), dryrun.py (multi-pod lower+
-# compile), train.py / serve.py (CLI drivers), steps.py (sharded step
-# builders), hlo_analysis.py (roofline accounting).
-#
-# NOTE: do not import dryrun from here — it sets XLA_FLAGS at import time.
-from repro.launch.mesh import make_production_mesh  # noqa: F401
+# Launch layer: train.py / serve.py (CLIs), steps.py (the step, pipeline,
+# controller, serving-front and supervisor builders the benchmark and the
+# CLIs call), hlo_analysis.py (roofline accounting), compile_cache.py
+# (persistent compile cache).

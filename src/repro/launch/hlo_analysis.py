@@ -12,10 +12,9 @@ text into a call graph and propagates three metrics with known trip counts:
                            instruction (post-fusion ⇒ a reasonable HBM-
                            traffic proxy; intra-fusion temporaries excluded)
 
-Known limitations (documented for EXPERIMENTS.md): non-dot FLOPs
-(convolutions, transcendentals) are not counted; dynamic trip counts
-default to 1; all-reduce bytes are counted once (not 2x for its
-reduce-scatter + all-gather decomposition).
+Known limitations: non-dot FLOPs (convolutions, transcendentals) are not
+counted; dynamic trip counts default to 1; all-reduce bytes are counted
+once (not 2x for its reduce-scatter + all-gather decomposition).
 """
 from __future__ import annotations
 
@@ -36,45 +35,11 @@ COLLECTIVES = {"all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute-start", "ragged-all-to-all"}
 
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
-_RG_IOTA_RE = re.compile(
-    r"replica_groups=\[(\d+),(\d+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?")
-_RG_LIST_RE = re.compile(r"replica_groups=\{\{([0-9,{} ]*)\}\}")
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(?.*?\)?)\s+([a-z][\w\-]*)\((.*)$")
 _TRIP_RE = re.compile(r'"known_trip_count":\s*\{"n":\s*"(\d+)"\}')
-_CALLEE_ATTR_RE = re.compile(
-    r"(?:body|to_apply|calls|condition)=%?([\w.\-]+)")
 _CALLEE_LIST_RE = re.compile(
     r"(?:called_computations|branch_computations)=\{([^}]*)\}")
-
-
-def crosses_pod(attr_text: str, pod_size: int = 256,
-                total_devices: int = 0) -> bool:
-    """True if any replica group spans the pod boundary (multi-pod DCN
-    traffic).  Handles the iota form ``replica_groups=[G,S]<=[dims]``,
-    the explicit list form, and EMPTY groups (= all devices)."""
-    import numpy as np
-    if "replica_groups={}" in attr_text:
-        return total_devices > pod_size
-    m = _RG_IOTA_RE.search(attr_text)
-    if m:
-        g, s = int(m.group(1)), int(m.group(2))
-        dims = [int(x) for x in m.group(3).split(",")]
-        ids = np.arange(int(np.prod(dims))).reshape(dims)
-        if m.group(4):
-            perm = [int(x) for x in m.group(4).split(",")]
-            ids = ids.transpose(perm)
-        groups = ids.reshape(g, s)
-        pods = groups // pod_size
-        return bool(np.any(pods.min(axis=1) != pods.max(axis=1)))
-    m = _RG_LIST_RE.search(attr_text)
-    if m:
-        for grp in m.group(1).split("},{"):
-            ids = [int(x) for x in grp.replace("{", "").replace("}", "")
-                   .split(",") if x.strip()]
-            if ids and (min(ids) // pod_size) != (max(ids) // pod_size):
-                return True
-    return False
 
 
 def _shapes(text: str) -> List[Tuple[str, List[int]]]:
@@ -99,7 +64,6 @@ def _nbytes(text: str) -> int:
 class Metrics:
     dot_flops: float = 0.0
     collective_bytes: float = 0.0
-    cross_pod_bytes: float = 0.0
     traffic_bytes: float = 0.0
     coll_by_op: Dict[str, float] = field(default_factory=dict)
     coll_counts: Dict[str, int] = field(default_factory=dict)
@@ -108,7 +72,6 @@ class Metrics:
             include_traffic: bool = True):
         self.dot_flops += mult * other.dot_flops
         self.collective_bytes += mult * other.collective_bytes
-        self.cross_pod_bytes += mult * other.cross_pod_bytes
         if include_traffic:
             self.traffic_bytes += mult * other.traffic_bytes
         for k, v in other.coll_by_op.items():
@@ -156,8 +119,7 @@ def _parse_dot_flops(result_txt: str, args_txt: str,
     return 2.0 * out_elems * k
 
 
-def parse_hlo(hlo_text: str,
-              total_devices: int = 0) -> Dict[str, Computation]:
+def parse_hlo(hlo_text: str) -> Dict[str, Computation]:
     comps: Dict[str, Computation] = {}
     current: Optional[Computation] = None
     symbols: Dict[str, List[int]] = {}
@@ -215,8 +177,6 @@ def parse_hlo(hlo_text: str,
             base = op.replace("-start", "")
             b = _nbytes(result_txt)
             met.collective_bytes += b
-            if crosses_pod(rest, total_devices=total_devices):
-                met.cross_pod_bytes += b
             met.coll_by_op[base] = met.coll_by_op.get(base, 0.0) + b
             met.coll_counts[base] = met.coll_counts.get(base, 0) + 1
         # call edges with loop multipliers; fusion bodies don't add traffic
@@ -263,12 +223,11 @@ def aggregate(comps: Dict[str, Computation]) -> Metrics:
     return total(root) if root else Metrics()
 
 
-def analyze(hlo_text: str, total_devices: int = 0) -> Dict:
-    met = aggregate(parse_hlo(hlo_text, total_devices))
+def analyze(hlo_text: str) -> Dict:
+    met = aggregate(parse_hlo(hlo_text))
     return {
         "dot_flops": met.dot_flops,
         "collective_bytes": met.collective_bytes,
-        "cross_pod_bytes": met.cross_pod_bytes,
         "traffic_bytes": met.traffic_bytes,
         "coll_by_op": met.coll_by_op,
         "coll_counts": met.coll_counts,

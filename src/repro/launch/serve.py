@@ -1,6 +1,6 @@
 """Serving launcher — a thin CLI over the ``repro.serve`` engine
-(batched prefill + continuous-batching decode for any architecture;
-reduced configs run for real on this host, full configs via dryrun).
+(batched prefill + continuous-batching decode for any architecture's
+reduced config, run for real on this host).
 
 ``--disagg`` serves through the disaggregated front instead: prefill
 specialists feeding decode engines over the cache-migration channel,
@@ -49,7 +49,7 @@ def main():
     cfg = get_reduced(args.arch)
     if cfg.is_encoder_only:
         raise SystemExit(f"{args.arch} is encoder-only: no decode serving "
-                         f"(see DESIGN.md shape/skip matrix)")
+                         f"(repro.configs.shape_skips)")
     params = T.init_model(jax.random.key(args.seed), cfg)
     shape = InputShape("serve", args.prompt_len, args.batch, "prefill")
     batch = make_batch(cfg, shape, seed=args.seed)

@@ -1,239 +1,27 @@
-"""Step builders: jitted train / prefill / serve steps with full sharding
-annotations, plus ShapeDtypeStruct input factories for the dry-run.
+"""Launch-time builders: the launcher, not the algorithm module, decides
+which hot path a step compiles to and how the experience pipeline is laid
+out over GMIs.  The benchmark (``bench/``), ``chip_smoke.py`` and the
+CLIs call:
 
-LGR on the production mesh (DESIGN.md §2): the gradient-reduction schedule
-is selected through the parameter LAYOUT, exactly the paper's insight that
-the layout determines the schedule —
-
-* ``--lgr mrr`` (flat)        : params replicated over (pod, data); autodiff
-  gradient sync lowers to ONE flat all-reduce ring over every chip.
-* ``--lgr har`` (hierarchical): params FSDP-sharded over ``data``,
-  replicated over ``pod``; gradient sync lowers to reduce-scatter(data/ICI)
-  → cross-pod all-reduce on 1/16-size shards → all-gather(data/ICI) — the
-  paper's intra-reduce → leader-ring → broadcast, with each chip the leader
-  of its shard slice.  Cross-pod (DCN) bytes drop 16x.
-
-MPR (host-staged) is not expressible inside one HLO; it exists at the DRL
-layer (``repro.comm.mpr_host``) where the paper applies it.  The DRL
-builders below consume ``repro.comm.Communicator`` objects — the unified
-communication subsystem owning mesh + strategy + grad-sync — instead of
-string-passing schedule names.
+* :func:`make_drl_train_step` — the jitted sync-PPO iteration;
+* :func:`make_lm_policy_train_step` — the jitted GRPO step of a token
+  policy on the latent-attention MoE stack;
+* :func:`make_communicator`, :func:`make_experience_pipeline` — the
+  layout's ``repro.comm.Communicator`` and its channel pipeline;
+* :func:`make_online_controller`, :func:`make_async_runner` — the
+  Algorithm-2 controller and the async A3C runner over that pipeline;
+* :func:`make_disagg_front`, :func:`make_fleet_supervisor` — the
+  disaggregated serving front and the fault-tolerant fleet.
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Tuple
-
-import numpy as np
+from typing import Dict, Optional
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import InputShape, ModelConfig, TrainConfig
-from repro.dist.partition import (batch_specs, cache_specs, param_specs,
-                                  to_shardings)
-from repro.launch.mesh import batch_axes
-from repro.models import transformer as T
-from repro.optim import AdamState, adam_init, adam_update
+from repro.configs.base import ModelConfig
 
-
-# ----------------------------------------------------------- input specs ---
-def input_specs(cfg: ModelConfig, shape: InputShape) -> Dict:
-    """ShapeDtypeStruct stand-ins for every model input (no allocation)."""
-    B, S = shape.global_batch, shape.seq_len
-    dt = jnp.dtype(cfg.dtype)
-    i32 = jnp.int32
-    if shape.mode == "decode":
-        return {"token": jax.ShapeDtypeStruct((B,), i32),
-                "pos": jax.ShapeDtypeStruct((B,), i32)}
-    if cfg.frontend == "audio":
-        return {"features": jax.ShapeDtypeStruct((B, S, cfg.frontend_feat_dim), dt),
-                "mask": jax.ShapeDtypeStruct((B, S), jnp.bool_),
-                "targets": jax.ShapeDtypeStruct((B, S), i32)}
-    if cfg.frontend == "vision":
-        Tt = S - cfg.num_patches
-        return {"tokens": jax.ShapeDtypeStruct((B, Tt), i32),
-                "labels": jax.ShapeDtypeStruct((B, Tt), i32),
-                "patches": jax.ShapeDtypeStruct(
-                    (B, cfg.num_patches, cfg.frontend_feat_dim), dt)}
-    return {"tokens": jax.ShapeDtypeStruct((B, S), i32),
-            "labels": jax.ShapeDtypeStruct((B, S), i32)}
-
-
-def abstract_train_state(cfg: ModelConfig):
-    params = T.init_abstract(cfg)
-    opt = jax.eval_shape(adam_init, params)
-    return params, opt
-
-
-def abstract_cache(cfg: ModelConfig, shape: InputShape,
-                   window_override: Optional[int] = None,
-                   per_layer: bool = False):
-    return jax.eval_shape(
-        functools.partial(T.init_cache, cfg, shape.global_batch,
-                          shape.seq_len, window_override,
-                          per_layer=per_layer))
-
-
-# ------------------------------------------------------------- shardings ---
-def _act_spec(mesh, mode: str, kind: str = "dmodel"):
-    bt = batch_axes(mesh)
-    ax = bt if len(bt) > 1 else bt[0]
-    if kind == "none" or mode == "decode":
-        return None
-    if kind == "seq":
-        return P(ax, "model", None)
-    return P(ax, None, "model")
-
-
-def make_train_step(cfg: ModelConfig, mesh, shape: InputShape,
-                    train_cfg: TrainConfig = TrainConfig(),
-                    lgr: str = "har", act_sharding: str = "dmodel",
-                    moe_spec: str = "contract"):
-    """Returns (jitted_fn, example_args (SDS), arg_shardings)."""
-    fsdp = (lgr == "har")
-    params_sds, opt_sds = abstract_train_state(cfg)
-    pspecs = param_specs(params_sds, mesh, fsdp=fsdp, moe_spec=moe_spec)
-    ospecs = AdamState(step=P(),
-                       mu=param_specs(params_sds, mesh, fsdp=fsdp,
-                                      moe_spec=moe_spec),
-                       nu=param_specs(params_sds, mesh, fsdp=fsdp,
-                                      moe_spec=moe_spec))
-    batch_sds = input_specs(cfg, shape)
-    bspecs = batch_specs(batch_sds, mesh, batch_axes=batch_axes(mesh))
-    T.set_activation_sharding(_act_spec(mesh, shape.mode, act_sharding))
-    from repro.models.moe import set_moe_sharding
-    bt = batch_axes(mesh)
-    set_moe_sharding(bt if len(bt) > 1 else bt[0])
-
-    M = max(train_cfg.microbatches, 1)
-
-    def train_step(params, opt_state, batch):
-        def loss_of(b):
-            return lambda p: T.loss_fn(p, cfg, b, remat=train_cfg.remat)
-
-        if M == 1:
-            lval, grads = jax.value_and_grad(
-                lambda p: T.loss_fn(p, cfg, batch,
-                                    remat=train_cfg.remat))(params)
-        else:
-            # gradient accumulation: scan over M microbatches; activation
-            # memory scales 1/M, gradient-sync bytes unchanged (one sync)
-            mb = jax.tree.map(
-                lambda x: x.reshape((M, x.shape[0] // M) + x.shape[1:]),
-                batch)
-            acc0 = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-
-            def mb_step(carry, b):
-                acc, ltot = carry
-                lv, g = jax.value_and_grad(
-                    lambda p: T.loss_fn(p, cfg, b,
-                                        remat=train_cfg.remat))(params)
-                acc = jax.tree.map(
-                    lambda a, gg: a + gg.astype(jnp.float32) / M, acc, g)
-                return (acc, ltot + lv / M), None
-
-            (grads, lval), _ = jax.lax.scan(mb_step,
-                                            (acc0, jnp.float32(0.0)), mb)
-        params, opt_state = adam_update(
-            grads, opt_state, params, lr=train_cfg.learning_rate,
-            beta1=train_cfg.beta1, beta2=train_cfg.beta2,
-            weight_decay=train_cfg.weight_decay,
-            grad_clip=train_cfg.grad_clip)
-        return params, opt_state, {"loss": lval.astype(jnp.float32)}
-
-    fn = jax.jit(
-        train_step,
-        in_shardings=(to_shardings(pspecs, mesh),
-                      to_shardings(ospecs, mesh),
-                      to_shardings(bspecs, mesh)),
-        out_shardings=(to_shardings(pspecs, mesh),
-                       to_shardings(ospecs, mesh),
-                       NamedSharding(mesh, P())),
-        donate_argnums=(0, 1))
-    return fn, (params_sds, opt_sds, batch_sds)
-
-
-def make_prefill_step(cfg: ModelConfig, mesh, shape: InputShape,
-                      window_override: Optional[int] = None,
-                      act_sharding: str = "dmodel"):
-    params_sds = T.init_abstract(cfg)
-    pspecs = param_specs(params_sds, mesh, fsdp=False)
-    batch_sds = input_specs(cfg, shape)
-    bspecs = batch_specs(batch_sds, mesh, batch_axes=batch_axes(mesh))
-    cache_sds = abstract_cache(cfg, shape, window_override)
-    cspecs = cache_specs(cache_sds, mesh,
-                         batch_shardable=shape.global_batch > 1)
-    T.set_activation_sharding(_act_spec(mesh, shape.mode, act_sharding))
-    from repro.models.moe import set_moe_sharding
-    bt = batch_axes(mesh)
-    set_moe_sharding(bt if len(bt) > 1 else bt[0])
-
-    def prefill_step(params, batch):
-        logits, caches = T.prefill(params, cfg, batch, shape.seq_len,
-                                   window_override)
-        return logits.astype(jnp.float32), caches
-
-    fn = jax.jit(
-        prefill_step,
-        in_shardings=(to_shardings(pspecs, mesh),
-                      to_shardings(bspecs, mesh)),
-        out_shardings=(NamedSharding(mesh, P()),
-                       to_shardings(cspecs, mesh)))
-    return fn, (params_sds, batch_sds)
-
-
-def make_serve_step(cfg: ModelConfig, mesh, shape: InputShape,
-                    window_override: Optional[int] = None,
-                    cache_layout: str = "heads", params_fsdp: bool = False,
-                    unroll: bool = False, per_layer_cache: bool = False):
-    """One decode step over a seq_len-deep KV/state cache."""
-    per_layer_cache = per_layer_cache and cfg.local_global \
-        and not cfg.block_pattern
-    unroll = unroll or per_layer_cache
-    params_sds = T.init_abstract(cfg)
-    pspecs = param_specs(params_sds, mesh, fsdp=params_fsdp)
-    cache_sds = abstract_cache(cfg, shape, window_override,
-                               per_layer=per_layer_cache)
-    cspecs = cache_specs(cache_sds, mesh,
-                         batch_shardable=shape.global_batch > 1,
-                         layout=cache_layout)
-    tok_sds = input_specs(cfg, shape)
-    bspecs = batch_specs(tok_sds, mesh, batch_axes=batch_axes(mesh))
-    T.set_activation_sharding(None)
-    from repro.models.moe import set_moe_sharding
-    bt = batch_axes(mesh)
-    nb = 1
-    for a, s in zip(mesh.axis_names, mesh.axis_sizes):
-        if a in bt:
-            nb *= s
-    set_moe_sharding((bt if len(bt) > 1 else bt[0])
-                     if shape.global_batch % nb == 0 else None)
-
-    def serve_step(params, caches, token, pos):
-        logits, caches = T.decode_step(params, cfg, token, pos, caches,
-                                       window_override,
-                                       unroll=unroll and not cfg.block_pattern)
-        return logits.astype(jnp.float32), caches
-
-    fn = jax.jit(
-        serve_step,
-        in_shardings=(to_shardings(pspecs, mesh),
-                      to_shardings(cspecs, mesh),
-                      to_shardings(bspecs["token"], mesh),
-                      to_shardings(bspecs["pos"], mesh)),
-        out_shardings=(NamedSharding(mesh, P()),
-                       to_shardings(cspecs, mesh)),
-        donate_argnums=(1,))
-    return fn, (params_sds, cache_sds, tok_sds["token"], tok_sds["pos"])
-
-
-# ------------------------------------------------------------- DRL steps ---
-# The DRL layer's launch entry points, mirroring the LLM builders above:
-# the launcher (not the algorithm module) decides which hot path a step
-# compiles to and how the experience pipeline is laid out over GMIs.
 
 def make_communicator(layout, cost_model=None, *, average: bool = True,
                       with_mesh: bool = False, calibrate: bool = False):
@@ -251,19 +39,15 @@ def make_communicator(layout, cost_model=None, *, average: bool = True,
     return comm
 
 
-def make_drl_train_step(env, ppo_cfg=None, grad_sync_fn=None,
-                        fused: Optional[bool] = None, communicator=None):
+def make_drl_train_step(env, ppo_cfg=None, communicator=None):
     """Jitted sync-PPO iteration with the fused Pallas hot path on by
     default: the gae_scan kernel (GAE + advantage normalization in one
-    VMEM pass).  An explicit
-    ``ppo_cfg`` keeps its own ``use_fused_kernels`` unless ``fused``
-    explicitly overrides it.  Gradient sync comes from ``communicator``
-    (a ``repro.comm.Communicator``) when given, else ``grad_sync_fn``."""
+    VMEM pass).  An explicit ``ppo_cfg`` keeps its own
+    ``use_fused_kernels``.  Gradient sync comes from ``communicator`` (a
+    ``repro.comm.Communicator``) when given."""
     from repro.rl.ppo import PPOConfig, make_train_step
     cfg = ppo_cfg if ppo_cfg is not None \
         else PPOConfig(use_fused_kernels=True)
-    if fused is not None and fused != cfg.use_fused_kernels:
-        cfg = cfg._replace(use_fused_kernels=fused)
     if communicator is not None and communicator.mesh is not None:
         # same guard AsyncRunner applies: this builder jits an eager
         # per-instance step, and a mesh-attached Communicator's sync
@@ -274,8 +58,7 @@ def make_drl_train_step(env, ppo_cfg=None, grad_sync_fn=None,
             "mesh-attached Communicator's sync closure is SPMD-only (use "
             "Communicator.allreduce in a shard_map launcher, or a "
             "mesh-less Communicator here)")
-    sync = communicator if communicator is not None else grad_sync_fn
-    return make_train_step(env, cfg, sync), cfg
+    return make_train_step(env, cfg, communicator), cfg
 
 
 def make_lm_policy_train_step(model_cfg: ModelConfig, grpo_cfg=None,

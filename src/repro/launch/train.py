@@ -4,9 +4,8 @@ Two modes:
 * ``--workload drl``  — the paper's workload: multi-instance PPO with GMI
   layout templates and LGR gradient sync across instances (runs for real on
   this host's devices).
-* ``--workload lm``   — LLM-architecture training on a local mesh with the
-  reduced config (for full-size production meshes use
-  ``repro.launch.dryrun``).
+* ``--workload lm``   — LLM-architecture training of the reduced config on
+  this host's default device.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --workload drl --env Ant \
@@ -90,7 +89,7 @@ def run_lm(args):
     import jax
     import jax.numpy as jnp
     from repro.configs import get_reduced
-    from repro.configs.base import InputShape, TrainConfig
+    from repro.configs.base import InputShape
     from repro.data import make_batch
     from repro.models import transformer as T
     from repro.optim import adam_init, adam_update
@@ -101,14 +100,13 @@ def run_lm(args):
     key = jax.random.key(args.seed)
     params = T.init_model(key, cfg)
     opt = adam_init(params)
-    tc = TrainConfig(learning_rate=args.lr)
 
     @jax.jit
     def step(params, opt, batch):
         loss, grads = jax.value_and_grad(
             lambda p: T.loss_fn(p, cfg, batch, remat=False))(params)
-        params, opt = adam_update(grads, opt, params, lr=tc.learning_rate,
-                                  grad_clip=tc.grad_clip)
+        params, opt = adam_update(grads, opt, params, lr=args.lr,
+                                  grad_clip=1.0)
         return params, opt, loss
 
     t0 = time.time()

@@ -5,15 +5,15 @@ Two execution paths:
   * ``direct``  — materializes (…, Sq, Skv) scores; used for small sequences
     and as the oracle.
   * ``chunked`` — flash-style double-blocked online softmax expressed with
-    ``jax.lax.scan`` (O(block²) live scores); used for long sequences so the
-    32k/500k dry-run shapes fit HBM.  The Pallas kernel in
+    ``jax.lax.scan`` (O(block²) live scores); used for long sequences so
+    their scores fit HBM.  The Pallas kernel in
     ``repro.kernels.flash_attention`` is the TPU-tiled version of the same
     algorithm.
 
 Caches:
   * full cache  — (B, S, n_kv, hd) k/v with write index = absolute position.
   * ring cache  — (B, W, n_kv, hd) sliding-window ring buffer plus a
-    ``slot_pos`` (B, W) absolute-position map, for ``long_500k`` decode.
+    ``slot_pos`` (B, W) absolute-position map, for long-context decode.
   * paged cache — a batch-free pool of fixed-size pages
     (num_pages, page, n_kv, hd) addressed through a per-request page table
     (B, M): virtual page v of a request holds absolute positions

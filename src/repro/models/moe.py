@@ -5,13 +5,8 @@ expert held (mixtral, granite, serving).  ``routed_moe_apply`` (end of the
 file): DeepSeek-V3 routing over the published experts, of which this chip
 holds a share, dropless through the grouped expert kernel.
 
-Dispatch is *grouped*: tokens are routed within their (sharded) batch row.
-The scatter/gather is expressed BATCHED (leading B dim everywhere, no vmap)
-with explicit sharding constraints on every buffer — GSPMD cannot propagate
-the batch sharding through a scatter with computed indices, and without the
-constraints the expert intermediates materialize group-REPLICATED
-(measured: 8.75 GiB f32[8,256,1280,896] tensors on mixtral train_4k,
-~80 GiB/device total; see EXPERIMENTS.md §Perf Pair A).
+Dispatch is *grouped*: tokens are routed within their batch row.  The
+scatter/gather is expressed BATCHED (leading B dim everywhere, no vmap).
 
 Expert FFN weights carry the expert dim and are tensor-parallel over the
 ``model`` axis inside each expert (E rarely divides the 16-wide model
@@ -21,35 +16,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import mlp
 from repro.utils import lecun_init
-
-# ---------------------------------------------------------------------------
-# sharding hook (set by the launcher, like transformer.set_activation_sharding)
-_GROUP_AXIS = None
-_MODEL_AXIS = None
-
-
-def set_moe_sharding(group_axis, model_axis="model"):
-    """group_axis: mesh axis (or tuple) the batch/group dim shards over;
-    model_axis: TP axis the expert hidden dim (F) shards over."""
-    global _GROUP_AXIS, _MODEL_AXIS
-    _GROUP_AXIS = group_axis
-    _MODEL_AXIS = model_axis if group_axis is not None else None
-
-
-def _constrain(x, *tail):
-    """tail entries: None or "model" (resolved to the configured TP axis).
-    NOTE a PartitionSpec constraint is TOTAL — None dims force replication,
-    so the F dim must be named here or GSPMD computes the full unsharded
-    expert hidden per device (measured 3.1x dot-FLOPs on mixtral)."""
-    if _GROUP_AXIS is None:
-        return x
-    spec = [_GROUP_AXIS] + [(_MODEL_AXIS if t == "model" else t)
-                            for t in tail[:x.ndim - 1]]
-    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def init_moe(key, d_model: int, d_ff: int, num_experts: int):
@@ -101,15 +70,12 @@ def _dispatch(params, x, logits, num_experts: int, top_k: int,
     bidx = jnp.arange(B)[:, None]
     buf = jnp.zeros((B, E * capacity + 1, D), dt)
     buf = buf.at[bidx, flat_slot].add(xk)
-    buf = _constrain(buf, None, None)
     bufe = buf[:, :-1].reshape(B, E, capacity, D)
-    bufe = _constrain(bufe, None, None, None)
 
     h = jnp.einsum("becd,edf->becf", bufe, params["wi"].astype(dt))
     g = jnp.einsum("becd,edf->becf", bufe, params["wg"].astype(dt))
-    h = _constrain(jax.nn.silu(g) * h, None, None, "model")
+    h = jax.nn.silu(g) * h
     out_buf = jnp.einsum("becf,efd->becd", h, params["wo"].astype(dt))
-    out_buf = _constrain(out_buf, None, None, None)
 
     out_flat = jnp.concatenate(
         [out_buf.reshape(B, E * capacity, D),
@@ -118,7 +84,7 @@ def _dispatch(params, x, logits, num_experts: int, top_k: int,
     y = y.reshape(B, S, k, D)
     w = (gate * valid.astype(gate.dtype))[..., None]
     out = jnp.sum(y * w.astype(y.dtype), axis=2)
-    return _constrain(out, None, None)
+    return out
 
 
 def moe_apply(params, x, *, num_experts: int, top_k: int,

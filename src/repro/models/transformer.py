@@ -5,8 +5,8 @@ Layer stacking strategy
 -----------------------
 * homogeneous stacks (dense/moe/audio/vlm): one stacked params pytree with
   leading dim = num_layers, applied with ``jax.lax.scan`` so the compiled HLO
-  contains ONE layer body regardless of depth (critical for the 80 dry-run
-  compiles on a single CPU core).
+  contains ONE layer body regardless of depth, so compile time does not
+  grow with it.
 * patterned stacks (xlstm: 7×mlstm+1×slstm; zamba2: 8×mamba2+1×shared-attn):
   scan over ``num_super`` super-blocks; inside the scan body the pattern is
   unrolled (static, short).  zamba2's shared attention block reuses ONE weight
@@ -30,24 +30,6 @@ from repro.models.layers import (embed, init_embedding, init_linear, init_mlp,
 from repro.models.moe import init_moe, moe_apply
 
 BIG_WINDOW = 1 << 30  # "no window" sentinel usable as a dynamic operand
-
-# --------------------------------------------------------------------------
-# Activation-sharding hook (sequence-parallel style): when set (by the
-# launcher, under a mesh context), the scan-carried hidden state is
-# constrained to this PartitionSpec at every layer boundary so the remat
-# stash is sharded instead of replicated over the model axis.
-_ACT_SPEC = None
-
-
-def set_activation_sharding(spec):
-    global _ACT_SPEC
-    _ACT_SPEC = spec
-
-
-def _constrain(x):
-    if _ACT_SPEC is not None and x.ndim >= 3:
-        x = jax.lax.with_sharding_constraint(x, _ACT_SPEC)
-    return x
 
 
 # ======================================================================
@@ -372,7 +354,6 @@ def apply_stack(params, cfg: ModelConfig, x, positions, caches=None,
     def body(carry, xs):
         h, aux = carry
         lp, window, cache = xs
-        h = _constrain(h)
         h2, new_cache, a = _attn_block(lp, h, cfg, positions, window, cache,
                                        page_table, paged_kernel)
         return (h2, aux + a), new_cache
@@ -392,7 +373,6 @@ def _apply_patterned(params, cfg, x, positions, caches, window_override,
     def body(carry, xs):
         h, aux = carry
         sup_params, sup_caches = xs
-        h = _constrain(h)
         new_caches = {}
         for i, kind in enumerate(pat):
             key = f"{kind}_{i}"

@@ -1,8 +1,7 @@
 """Multi-device distribution tests via subprocess (8 fake host devices).
 
 A subprocess is mandatory: jax locks the device count at first init, and
-the main pytest process must keep seeing ONE device (per the dry-run
-contract)."""
+the main pytest process must keep seeing ONE device."""
 import os
 import subprocess
 import sys

@@ -1,5 +1,7 @@
 """Partition-spec rules validated against every architecture on an abstract
 16x16 (and 2x16x16) mesh — no devices needed."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -7,9 +9,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import ARCHS, get_config
 from repro.dist.partition import batch_specs, cache_specs, param_specs
-from repro.launch.steps import abstract_cache, input_specs
-from repro.configs.base import INPUT_SHAPES
 from repro.models import transformer as T
+
 
 def _amesh(sizes, names):
     try:                                  # jax >= 0.5 signature
@@ -52,15 +53,15 @@ def test_param_specs_divisible(arch, mesh):
                                   "xlstm-1.3b"])
 def test_cache_specs_divisible(arch):
     cfg = get_config(arch)
-    shape = INPUT_SHAPES["decode_32k"]
-    caches = abstract_cache(cfg, shape)
+    # a decode cache of 128 sequences x 32768 positions, never allocated
+    caches = jax.eval_shape(functools.partial(T.init_cache, cfg, 128, 32768))
     specs = cache_specs(caches, MESH)
     _check_divisibility(caches, specs, MESH)
 
 
 def test_tp_sharding_hits_big_matrices():
     """The model axis must actually shard the projections of a big config
-    (otherwise the dry-run silently replicates 72B params)."""
+    (otherwise a sharded step silently replicates 72B params)."""
     cfg = get_config("qwen2-72b")
     params = T.init_abstract(cfg)
     specs = param_specs(params, MESH, fsdp=True)
@@ -85,9 +86,9 @@ def test_granite_vocab_fallback():
 
 
 def test_batch_specs():
-    shape = INPUT_SHAPES["train_4k"]
-    cfg = get_config("internlm2-1.8b")
-    sds = input_specs(cfg, shape)
+    # a train batch of 256 sequences x 4096 tokens, never allocated
+    sds = {k: jax.ShapeDtypeStruct((256, 4096), jnp.int32)
+           for k in ("tokens", "labels")}
     specs = batch_specs(sds, MESH, batch_axes=("data",))
     assert tuple(specs["tokens"])[0] == "data"
     specs3 = batch_specs(sds, MESH3, batch_axes=("pod", "data"))
