@@ -40,13 +40,20 @@ def _interpret_default() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
-                                             "block_q", "block_k",
-                                             "interpret"))
-def attention(q, k, v, *, causal=True, window=None, softcap=None,
-              block_q=128, block_k=128, interpret=None):
+                                             "dot_dtype", "block_q",
+                                             "block_k", "interpret"))
+def attention(q, k, v, lengths=None, *, causal=True, window=None,
+              softcap=None, dot_dtype=None, block_q=128, block_k=128,
+              interpret=None):
+    """Block-skipping flash attention (see flash_attention.py), with a
+    custom VJP.  ``lengths`` (B,): real positions per right-padded row;
+    rows past it come back zero.  ``dot_dtype``: the products' operand
+    dtype (``None``: q's).  The kernels' instructions are named
+    ``attention`` (forward), ``attention_dq`` and ``attention_dkv``."""
     interp = _interpret_default() if interpret is None else interpret
-    return _fa(q, k, v, causal=causal, window=window, softcap=softcap,
-               block_q=block_q, block_k=block_k, interpret=interp)
+    return _fa(q, k, v, lengths, causal=causal, window=window,
+               softcap=softcap, dot_dtype=dot_dtype, block_q=block_q,
+               block_k=block_k, interpret=interp)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "scale", "interpret"))

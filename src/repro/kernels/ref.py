@@ -8,8 +8,10 @@ NEG_INF = -1e30
 
 
 def attention_ref(q, k, v, *, causal=True, window=None, softcap=None,
-                  scale=None):
-    """q: (B, Sq, H, hd); k/v: (B, Skv, KH, hd) with H % KH == 0."""
+                  scale=None, lengths=None):
+    """q: (B, Sq, H, hd); k: (B, Skv, KH, hd); v: (B, Skv, KH, dv) with
+    H % KH == 0.  ``lengths`` (B,): real positions of each right-padded
+    row; keys past it are masked and query rows past it are zero."""
     B, Sq, H, hd = q.shape
     Skv, KH = k.shape[1], k.shape[2]
     G = H // KH
@@ -20,15 +22,19 @@ def attention_ref(q, k, v, *, causal=True, window=None, softcap=None,
         s = jnp.tanh(s / softcap) * softcap
     qpos = jnp.arange(Sq)[:, None]
     kpos = jnp.arange(Skv)[None, :]
-    mask = jnp.ones((Sq, Skv), bool)
+    mask = jnp.ones((1, Sq, Skv), bool)
     if causal:
         mask &= kpos <= qpos
     if window is not None:
         mask &= kpos > qpos - window
-    s = jnp.where(mask[None, None, None], s, NEG_INF)
+    if lengths is not None:
+        mask &= kpos < lengths[:, None, None]
+    s = jnp.where(mask[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
+    if lengths is not None:
+        p = jnp.where((qpos < lengths[:, None, None])[:, None, None], p, 0.0)
     o = jnp.einsum("bkgqs,bskd->bqkgd", p, v.astype(jnp.float32))
-    return o.reshape(B, Sq, H, hd).astype(q.dtype)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def paged_attention_ref(q, k_pages, v_pages, slot_pos, table, positions, *,

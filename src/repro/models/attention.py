@@ -8,7 +8,8 @@ Two execution paths:
     ``jax.lax.scan`` (O(block²) live scores); used for long sequences so
     their scores fit HBM.  The Pallas kernel in
     ``repro.kernels.flash_attention`` is the TPU-tiled version of the same
-    algorithm.
+    algorithm; :func:`mla` runs its long rows through that kernel, which
+    skips the blocks above the causal diagonal and past each row's length.
 
 Caches:
   * full cache  — (B, S, n_kv, hd) k/v with write index = absolute position.
@@ -307,9 +308,20 @@ def attention(params, x, *, num_heads: int, num_kv_heads: int, head_dim: int,
 
 
 # --------------------------------------------------------------------------
-def mla(params, x, positions, *, num_heads: int, qk_nope_dim: int,
-        qk_rope_dim: int, v_dim: int, rope_theta: float, norm_eps: float,
-        chunked_threshold: int = 4096):
+# (query, key) blocks of the flash kernel on mla's long rows (PERF.md
+# section 3 gives the choice)
+MLA_BLOCKS = (512, 512)
+
+
+def mla(params, x, positions, **kw):
+    """:func:`mla_with_blocks`' output alone."""
+    return mla_with_blocks(params, x, positions, **kw)[0]
+
+
+def mla_with_blocks(params, x, positions, *, num_heads: int,
+                    qk_nope_dim: int, qk_rope_dim: int, v_dim: int,
+                    rope_theta: float, norm_eps: float,
+                    chunked_threshold: int = 4096, lengths=None):
     """Multi-head latent attention (DeepSeek-V2/V3) without a query LoRA,
     for a training pass: full keys and values are built from the latent.
 
@@ -319,7 +331,20 @@ def mla(params, x, positions, *, num_heads: int, qk_nope_dim: int,
     ``(q_nope k_nope + q_r k_r) / sqrt(qk_nope_dim + qk_rope_dim)``; the
     ``v_dim``-wide head outputs go through ``W_o``.  Rope pairs use
     :func:`apply_rope`'s half-split layout.  x: (B, S, D); positions:
-    (S,) or (B, S)."""
+    (S,) or (B, S).
+
+    Rows longer than ``chunked_threshold`` run the flash kernel
+    (``kernels/ops.py::attention``, blocks ``MLA_BLOCKS``), causal by the
+    row's index; ``lengths`` (B,) says how many leading positions of each
+    right-padded row are real (``None``: all), and the outputs past it are
+    zero.  Shorter rows attend directly over every position.
+
+    Returns the output (B, S, D) and the counters ``attn_blocks``, the
+    ``MLA_BLOCKS`` score blocks the call computed (the kernel's: those
+    holding a causal pair inside a row's length; the direct path's: all),
+    and ``attn_blocks_full``, those of the full squares."""
+    from repro.kernels import ops
+    from repro.kernels.flash_attention import block_counts
     B, S, _ = x.shape
     H, dn, dr = num_heads, qk_nope_dim, qk_rope_dim
     if positions.ndim == 1:
@@ -334,8 +359,21 @@ def mla(params, x, positions, *, num_heads: int, qk_nope_dim: int,
     k = jnp.concatenate(
         [kv[..., :dn], jnp.broadcast_to(k_r, (B, S, H, dr))], -1)
     v = kv[..., dn:]
-    scale = (dn + dr) ** -0.5
-    attend = _chunked_attention if S > chunked_threshold \
-        else _direct_attention
-    out = attend(q, k, v, positions, positions, True, None, None, scale)
-    return linear(params["wo"], out.reshape(B, S, H * v_dim))
+    bq, bk = MLA_BLOCKS
+    blocks, full = block_counts(
+        jnp.full((B,), S) if lengths is None else lengths, S, S,
+        block_q=bq, block_k=bk)
+    if S > chunked_threshold:
+        # the kernel scales by 1 / sqrt(dn + dr), q's width; its products
+        # are float32 ones at the default precision: one bf16 pass with
+        # f32 accumulation on the TPU, float32 elsewhere
+        out = ops.attention(
+            q, k, v, lengths, causal=True, block_q=bq, block_k=bk,
+            dot_dtype=jnp.bfloat16 if jax.default_backend() == "tpu"
+            else None)
+    else:
+        out = _direct_attention(q, k, v, positions, positions, True, None,
+                                None, (dn + dr) ** -0.5)
+        blocks = full
+    out = linear(params["wo"], out.reshape(B, S, H * v_dim))
+    return out, {"attn_blocks": blocks, "attn_blocks_full": full}

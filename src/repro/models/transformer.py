@@ -644,28 +644,33 @@ def init_latent_moe(key, cfg: ModelConfig) -> Dict[str, Any]:
 
 
 def _latent_layer(lp, x, cfg: ModelConfig, positions, valid, first_expert):
-    from repro.models.attention import mla
+    from repro.models.attention import mla_with_blocks
     from repro.models.moe import routed_moe_apply
     h = rms_norm(lp["ln1"], x, cfg.norm_eps)
     with jax.named_scope("mla"):
-        x = x + mla(lp["mla"], h, positions, num_heads=cfg.num_heads,
-                    qk_nope_dim=cfg.qk_nope_head_dim,
-                    qk_rope_dim=cfg.qk_rope_head_dim,
-                    v_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta,
-                    norm_eps=cfg.norm_eps)
+        a, counters = mla_with_blocks(
+            lp["mla"], h, positions, num_heads=cfg.num_heads,
+            qk_nope_dim=cfg.qk_nope_head_dim,
+            qk_rope_dim=cfg.qk_rope_head_dim, v_dim=cfg.v_head_dim,
+            rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
+            lengths=valid.sum(-1))
+        x = x + a
     h = rms_norm(lp["ln2"], x, cfg.norm_eps)
     if "moe" in lp:
-        m, counters = routed_moe_apply(
+        m, moe = routed_moe_apply(
             lp["moe"], h, valid, top_k=cfg.experts_per_token,
             routed_scale=cfg.routed_scale, first_expert=first_expert)
-        return x + m, counters
-    return x + mlp(lp["mlp"], h, cfg.act), None
+        return x + m, dict(moe, **counters)
+    return x + mlp(lp["mlp"], h, cfg.act), counters
 
 
 def latent_moe_hidden(params, cfg: ModelConfig, tokens, valid,
                       first_expert: int = 0):
     """tokens, valid: (B, S) -> (final-normed hidden (B, S, D), counters
-    of the MoE layers, each stacked (layers, ...)).  Each layer is
+    of the MoE layers, each stacked (layers, ...), and ``attn_blocks`` /
+    ``attn_blocks_full``: the attention block pairs one pass computes and
+    those of the full squares, summed over every layer).  Rows are
+    right-padded: ``valid`` is a prefix of each row.  Each layer is
     rematerialized under a gradient."""
     x = embed(params["embed"], tokens).astype(jnp.dtype(cfg.dtype))
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
@@ -674,17 +679,20 @@ def latent_moe_hidden(params, cfg: ModelConfig, tokens, valid,
         return _latent_layer(lp, h, cfg, positions, valid, first_expert)
 
     body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, params["dense"])
+    x, dense = jax.lax.scan(body, x, params["dense"])
     x, counters = jax.lax.scan(body, x, params["layers"])
+    for k, n in dense.items():          # the attention blocks
+        counters[k] = jnp.sum(n) + jnp.sum(counters[k])
     return rms_norm(params["final_norm"], x, cfg.norm_eps), counters
 
 
 def token_logprobs(params, cfg: ModelConfig, tokens, valid,
                    first_expert: int = 0, chunk: int = 512):
     """Log-probability of each next token over the vocabulary slice:
-    (B, S - 1) for ``tokens[:, 1:]``, plus the MoE counters.  The head is
-    applied over position chunks (as ``_chunked_xent``), each
-    rematerialized, so (B, S, V) logits never exist at once."""
+    (B, S - 1) for ``tokens[:, 1:]``, plus the counters of
+    :func:`latent_moe_hidden`.  The head is applied over position chunks
+    (as ``_chunked_xent``), each rematerialized, so (B, S, V) logits never
+    exist at once."""
     h, counters = latent_moe_hidden(params, cfg, tokens, valid,
                                     first_expert)
     h, labels = h[:, :-1], tokens[:, 1:]

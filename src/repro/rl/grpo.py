@@ -62,8 +62,12 @@ def lm_pg_loss(params, model_cfg, tokens, valid, response, old_logp, advs,
 def train_step(params, opt_state: AdamState, batch: TokenBatch, cfg:
                GRPOConfig, model_cfg, first_expert: int = 0):
     """One GRPO step.  Returns (params, opt_state, metrics): the mean
-    minibatch loss, the response tokens trained, and the MoE counters of
-    the training passes summed over minibatches (per MoE layer)."""
+    minibatch loss, the response tokens trained, and the counters of the
+    training passes summed over minibatches: the MoE counters (per MoE
+    layer) and ``attn_blocks`` / ``attn_blocks_full``, the attention block
+    pairs computed and those of the full squares (summed over layers; each
+    minibatch's recompute, backward and old log-probabilities compute the
+    same blocks again)."""
     M = cfg.num_minibatches
     advs = group_advantages(batch.rewards, cfg.adv_eps)
     mb = jax.tree.map(lambda x: x.reshape((M, x.shape[0] // M)

@@ -51,6 +51,123 @@ def test_flash_attention_variants(window, softcap, causal):
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("length", [5, 16, 23, 40],
+                         ids=["below_a_block", "on_a_block_edge",
+                              "between_edges", "whole_row"])
+@pytest.mark.parametrize("window,dot_dtype,tol", [
+    (None, None, 1e-5), (7, None, 1e-5), (None, jnp.bfloat16, 2e-2)],
+    ids=["causal", "window", "causal_bf16_products"])
+def test_flash_attention_lengths_output_and_grads(length, window,
+                                                  dot_dtype, tol):
+    """Right-padded rows (one of ``length``, one of 37 real positions)
+    against the float32 oracle under a causal and length mask: the output
+    and the gradients of q, k and v (the backward refuses a window).
+    Values narrower than queries and keys (latent attention's 192/128,
+    scaled down), GQA, S = 40 not a multiple of the 16 x 8 blocks.  With
+    bf16 products (float32 ones at the TPU's default precision) the
+    kernel agrees to bf16 rounding.  Rows at or past a length are exactly
+    zero and get an exactly zero gradient."""
+    B, S, H, KH, hd, dv = 2, 40, 4, 2, 24, 16
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, S, H, hd))
+    k = jax.random.normal(ks[1], (B, S, KH, hd))
+    v = jax.random.normal(ks[2], (B, S, KH, dv))
+    w = jax.random.normal(ks[3], (B, S, H, dv))
+    lengths = jnp.array([length, 37])
+
+    def direct(q, k, v):
+        return ref.attention_ref(q, k, v, causal=True, window=window,
+                                 lengths=lengths)
+
+    def kernel(q, k, v):
+        return ops.attention(q, k, v, lengths, causal=True, window=window,
+                             dot_dtype=dot_dtype, block_q=16, block_k=8)
+
+    def grads(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a) * w), (0, 1, 2))(q, k, v)
+
+    out = kernel(q, k, v)
+    assert out.shape == (B, S, H, dv) and out.dtype == jnp.float32
+    np.testing.assert_allclose(out, direct(q, k, v), rtol=tol, atol=tol)
+    if window is not None:
+        with pytest.raises(NotImplementedError):
+            grads(kernel)
+        return
+    got, want = grads(kernel), grads(direct)
+    for g, gw in zip(got, want):
+        assert g.dtype == jnp.float32
+        np.testing.assert_allclose(g, gw, rtol=tol, atol=tol)
+    for b, n in enumerate(np.asarray(lengths)):
+        assert np.all(np.asarray(out)[b, n:] == 0)
+        assert np.all(np.asarray(got[0])[b, n:] == 0)
+
+
+def test_flash_attention_residual_and_backward_kernels():
+    """The forward kernel's log-sum-exp residual and the ``dq`` and
+    ``dk``/``dv`` kernels on their own, in the kernels' layout (rows of
+    batch x heads, sequence padded to whole blocks), against the oracle's
+    scores and its VJP, on rows of 23 and 40 real positions."""
+    from repro.kernels import flash_attention as fa
+    B, S, H, hd, dv = 2, 40, 2, 24, 16
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, S, H, hd))
+    k = jax.random.normal(ks[1], (B, S, H, hd))
+    v = jax.random.normal(ks[2], (B, S, H, dv))
+    g = jax.random.normal(ks[3], (B, S, H, dv))
+    L = jnp.array([23, 40])
+    c = fa._config(q, k, v, causal=True, window=None, softcap=None,
+                   scale=None, dot_dtype=None, block_q=16, block_k=8,
+                   interpret=True)
+    qh, kh, vh = fa._operands(q, k, v, c)
+    o, lse = fa._forward(L, qh, kh, vh, c, residuals=True)
+
+    s = jnp.einsum("bqhd,bshd->bhqs", q * hd ** -0.5, k)
+    pos = jnp.arange(S)
+    live = (pos[None, :] <= pos[:, None]) & (pos[None, :] < L[:, None, None])
+    want = jax.nn.logsumexp(jnp.where(live[:, None], s, -jnp.inf), -1)
+    got = lse[:, 0, :S].reshape(B, H, S)
+    for b, n in enumerate(np.asarray(L)):
+        np.testing.assert_allclose(got[b, :, :n], want[b, :, :n],
+                                   rtol=1e-5, atol=1e-5)
+
+    do = fa._heads_major(g, qh.shape[1], jnp.float32)
+    di = jnp.sum(do * o, axis=-1)[:, None, :]
+    dq, dk, dv_ = fa._backward(L, qh, kh, vh, do, lse, di, c)
+    _, vjp = jax.vjp(lambda q, k, v: ref.attention_ref(
+        q, k, v, causal=True, lengths=L), q, k, v)
+    for got, want in zip((dq, dk, dv_), vjp(g)):
+        np.testing.assert_allclose(fa._seq_major(got, B, S, jnp.float32),
+                                   want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 5),
+                                           (False, None), (False, 5)])
+def test_flash_block_state_is_the_pairs_of_the_block(causal, window):
+    """A block pair is computed (``live``) exactly when one of its
+    (query, key) pairs is live, and left unmasked (``full``) exactly when
+    all are: the predicate against every element of every block."""
+    from repro.kernels.flash_attention import block_state
+    bq, bk, Sq, Skv = 8, 4, 40, 36
+    qpos = np.arange(Sq)[:, None]
+    kpos = np.arange(Skv)[None, :]
+    for qlen, klen in [(40, 36), (13, 13), (8, 30), (1, 1), (0, 0)]:
+        ok = (qpos < qlen) & (kpos < klen)
+        if causal:
+            ok &= kpos <= qpos
+        if window is not None:
+            ok &= kpos > qpos - window
+        for q0 in range(0, Sq, bq):
+            for k0 in range(0, Skv, bk):
+                pairs = ok[q0:q0 + bq, k0:k0 + bk]
+                live, full = block_state(q0, k0, qlen, klen, block_q=bq,
+                                         block_k=bk, causal=causal,
+                                         window=window)
+                assert bool(live) == pairs.any(), (qlen, klen, q0, k0)
+                # blocks reaching past the arrays are never left unmasked
+                whole = q0 + bq <= Sq and k0 + bk <= Skv and pairs.all()
+                assert bool(full) == whole, (qlen, klen, q0, k0)
+
+
 @pytest.mark.parametrize("dims", [
     (60, 256, 128, 64),          # Ant trunk
     (211, 512, 512, 512, 256),   # ShadowHand trunk

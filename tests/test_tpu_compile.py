@@ -126,6 +126,30 @@ def _gmm(s):
         _sds((8,), jnp.int32, s))
 
 
+def _attention(s, grad=False):
+    # Moonlight's latent attention core on one row of 8192 positions: 16
+    # heads, 192-wide queries and keys, 128-wide values, mla's blocks and
+    # its bf16 products on the TPU
+    from repro.models.attention import MLA_BLOCKS
+    f32 = jnp.float32
+    q, k = _sds((1, 8192, 16, 192), f32, s), _sds((1, 8192, 16, 192), f32, s)
+    v, lengths = _sds((1, 8192, 16, 128), f32, s), _sds((1,), jnp.int32, s)
+
+    def attend(q, k, v, lengths):
+        return ops.attention(q, k, v, lengths, causal=True,
+                             dot_dtype=jnp.bfloat16, block_q=MLA_BLOCKS[0],
+                             block_k=MLA_BLOCKS[1], interpret=False)
+
+    def attend_grad(*a):
+        return jax.grad(lambda *a: jnp.sum(attend(*a) ** 2), (0, 1, 2))(*a)
+    return jax.jit(attend_grad if grad else attend).lower(q, k, v, lengths)
+
+
+def _attention_grad(s):
+    # the forward with its residuals, then the custom VJP's dq and dk/dv
+    return _attention(s, grad=True)
+
+
 def _pallas_names(text):
     """Instruction names of the compiled Pallas kernels in ``text``."""
     return re.findall(r"^\s*(?:ROOT )?%(\S+) = .*tpu_custom_call", text,
@@ -133,15 +157,23 @@ def _pallas_names(text):
 
 
 @pytest.mark.parametrize("lower", [_env_mega_step, _pack_channels, _gae_norm,
-                                   _nstep_returns, _paged_attention, _gmm],
+                                   _nstep_returns, _paged_attention, _gmm,
+                                   _attention, _attention_grad],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(one_chip, lower):
     compiled = lower(one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    # a kernel's instruction is named after its jitted ops wrapper
+    # a kernel's instruction is named after its ops wrapper (a gradient's
+    # lowering runs the same wrapper's kernels)
+    wrapper = lower.__name__.lstrip("_").removesuffix("_grad")
     names = _pallas_names(compiled.as_text())
-    assert names and all(n.startswith(lower.__name__.lstrip("_"))
-                         for n in names), names
+    assert names and all(n.startswith(wrapper) for n in names), names
+    if wrapper == "attention":
+        # bench/metrics/expert_gmm_roofline.py counts gmm.N and tgmm.N
+        assert {n.split(".")[0] for n in names} == (
+            {"attention", "attention_dq", "attention_dkv"}
+            if lower is _attention_grad else {"attention"}), names
+        assert not any(n.startswith(("gmm", "tgmm")) for n in names), names
 
 
 # the programs in which the benchmark's kernel metrics find their kernel
